@@ -19,8 +19,8 @@ from repro.core import greediris
 g = generators.erdos_renyi(2000, 6.0, seed=1)
 nbr, prob, wt = padded_adjacency(g)
 key = jax.random.key(0)
-from repro.runtime.jaxcompat import make_mesh
-mesh = make_mesh((8,), ("machines",))
+from repro.launch.mesh import make_im_mesh
+mesh = make_im_mesh(8)
 res = {}
 for name, kw in (
     ("dense-gather", dict(shuffle="dense")),

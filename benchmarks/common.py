@@ -36,8 +36,10 @@ def timeit(fn, *args, warmup: int = 1, iters: int = 3,
 
 def run_devices(code: str, num_devices: int, timeout: int = 560) -> dict:
     """Run snippet with N fake host devices; snippet must print one
-    JSON object on its last line."""
+    JSON object on its last line.  The child is held to the CPU, so
+    it never tries to take a chip that its parent may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={num_devices}")
     env["PYTHONPATH"] = SRC

@@ -21,8 +21,8 @@ from repro.core.diffusion import influence
 g = generators.{gen}
 nbr, prob, wt = padded_adjacency(g)
 key = jax.random.key(0)
-from repro.runtime.jaxcompat import make_mesh
-mesh = make_mesh((8,), ("machines",))
+from repro.launch.mesh import make_im_mesh
+mesh = make_im_mesh(8)
 n = g.num_vertices
 res = {{}}
 for name, kind, alpha in (("greediris", "g", 1.0),

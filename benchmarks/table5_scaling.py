@@ -21,8 +21,8 @@ m = {m}
 g = generators.erdos_renyi(2000, 6.0, seed=1)
 nbr, prob, wt = padded_adjacency(g)
 key = jax.random.key(0)
-from repro.runtime.jaxcompat import make_mesh
-mesh = make_mesh((m,), ("machines",))
+from repro.launch.mesh import make_im_mesh
+mesh = make_im_mesh(m)
 fn, _, theta = greediris.build_round(
     mesh, ("machines",), n=g.num_vertices, theta={theta}, k={k},
     max_degree=g.max_in_degree(), model="IC", alpha_trunc={alpha})

@@ -14,6 +14,7 @@ import sys
 if os.environ.get("_IM_CHILD") != "1":
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"   # fake devices: never the chip
     env["_IM_CHILD"] = "1"
     raise SystemExit(subprocess.run([sys.executable] + sys.argv,
                                     env=env).returncode)
@@ -31,8 +32,8 @@ from repro.graphs.csr import padded_adjacency
 g = generators.erdos_renyi(2000, 8.0, seed=1)
 nbr, prob, wt = padded_adjacency(g)
 key = jax.random.key(0)
-from repro.runtime.jaxcompat import make_mesh
-mesh = make_mesh((8,), ("machines",))
+from repro.launch.mesh import make_im_mesh
+mesh = make_im_mesh(8)
 print(f"mesh: {mesh.shape} | graph n={g.num_vertices} m={g.num_edges}")
 
 for label, builder in (

@@ -142,9 +142,10 @@ def _ref_bytes(aval) -> int:
     inner = getattr(aval, "inner_aval", aval)
     shape = getattr(inner, "shape", None)
     dtype = getattr(inner, "dtype", None)
-    if shape is None or dtype is None:
+    itemsize = getattr(dtype, "itemsize", None)
+    if shape is None or itemsize is None:   # e.g. DMA semaphores
         return 0
-    return math.prod(shape) * dtype.itemsize if shape else dtype.itemsize
+    return math.prod(shape) * itemsize
 
 
 def launch_vmem_bytes(eqn) -> Tuple[int, dict]:
@@ -172,10 +173,12 @@ def launch_sites(jx) -> list[LaunchSite]:
             continue
         eqn = site.eqn
         info = eqn.params.get("name_and_src_info")
+        name = (eqn.params.get("name")
+                or getattr(info, "name", PALLAS_PRIMITIVE))
         grid_mapping = eqn.params.get("grid_mapping")
         vmem, by_space = launch_vmem_bytes(eqn)
         sites.append(LaunchSite(
-            name=getattr(info, "name", PALLAS_PRIMITIVE),
+            name=name,
             path=site.path,
             in_loop=site.in_loop,
             iterations=site.iterations,

@@ -89,18 +89,6 @@ def _axis_size(mesh, axes: Sequence[str]) -> int:
     return int(math.prod(mesh.shape[a] for a in axes))
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: top-level (>= 0.6, kwarg
-    check_vma) with fallback to jax.experimental.shard_map (0.4.x,
-    kwarg check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                 max_degree: int, model: str = "IC", delta: float = 0.077,
                 alpha_trunc: float = 1.0, aggregate: str = "gather",
@@ -462,7 +450,8 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
 
     specs_in = (P(), P(), P(), P())  # graph + key replicated
     specs_out = GreediRISOut(P(), P(), P(), P())
-    fn = _shard_map(shard_fn, mesh, specs_in, specs_out)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=specs_in,
+                       out_specs=specs_out, check_vma=False)
     return fn, n_pad, theta_local * m
 
 
@@ -533,5 +522,6 @@ def build_ripples_round(mesh, axes: Sequence[str], *, n: int, theta: int,
         cov = lax.psum(bitset.coverage_size(covered), axes)
         return seeds, cov
 
-    fn = _shard_map(shard_fn, mesh, (P(), P(), P(), P()), (P(), P()))
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(), P(), P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     return fn, theta_local * m

@@ -160,16 +160,16 @@ def _kernel(ids_ref, thr_ref, counts_in_ref, rows_ref, covers_in_ref,
 def _stream_kernel(ids_ref, thr_ref, counts_in_ref, stream_ref,
                    covers_in_ref, seeds_in_ref, covers_ref, seeds_ref,
                    counts_out_ref, rows_buf, ids_buf, row_sem, id_sem,
-                   *, block_w: int):
+                   *, block_w: int, c_chunk: int):
     """Multi-chunk pipelined receiver: the [R, C, W] candidate stream
     and its [R, C] ids stay in HBM/ANY; double-buffered
     ``make_async_copy``s pull chunk r+1's rows into the [2, C, W] VMEM
-    scratch (and its ids into the [2, C] SMEM scratch — only one
+    scratch (and its ids into the [2, 1, C] SMEM scratch — only one
     chunk's ids are ever scalar-resident, so SMEM pressure is O(C),
     not O(R*C)) while the shared insertion body consumes chunk r.
     Covers / seeds / counts never leave VMEM between chunks."""
     b, w = covers_ref.shape
-    r_total, c_chunk = stream_ref.shape[0], stream_ref.shape[1]
+    r_total = stream_ref.shape[0]
     k = seeds_ref.shape[1]
 
     covers_ref[...] = covers_in_ref[...]
@@ -200,7 +200,7 @@ def _stream_kernel(ids_ref, thr_ref, counts_in_ref, stream_ref,
         for dma in chunk_dma(slot, r):
             dma.wait()
         return _insert_candidates(
-            lambda c: ids_buf[slot, c],
+            lambda c: ids_buf[slot, 0, c],
             lambda c, s: rows_buf[slot, pl.ds(c, 1), pl.ds(s, block_w)],
             c_chunk, covers_ref, seeds_ref, thr_ref, counts,
             block_w=block_w, num_word_tiles=w // block_w, lane=lane)
@@ -238,6 +238,7 @@ def bucket_insert_chunk_pallas(seed_ids: jnp.ndarray, rows: jnp.ndarray,
         covers = jnp.pad(covers, ((0, 0), (0, wp - w)))
     covers_out, seeds_out, counts_out = pl.pallas_call(
         functools.partial(_kernel, block_w=bw),
+        name="bucket_insert_chunk",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),    # seed ids [1, C]
             pl.BlockSpec(memory_space=pltpu.VMEM),    # thresholds [B, 1]
@@ -290,16 +291,28 @@ def bucket_insert_stream_pallas(seed_ids: jnp.ndarray, rows: jnp.ndarray,
     if r == 0:
         return covers, counts, seeds
     bw, wp = _padded_w(w, block_w)
+    # Each chunk is DMA'd on its own, and Mosaic refuses a slice that
+    # is not a whole number of tiles: pad each chunk's rows to whole
+    # sublane tiles and its ids to whole lane tiles, and give each
+    # chunk's ids a unit axis so that the slice is on an untiled
+    # leading axis.  The kernel loops over the C real candidates
+    # only, so pads are never read.
+    cs = gain_core.padded_size(c, gain_core.SUBLANE)
+    cp = gain_core.padded_size(c, gain_core.LANE)
+    if wp != w or cs != c:
+        rows = jnp.pad(rows, ((0, 0), (0, cs - c), (0, wp - w)))
     if wp != w:
-        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, wp - w)))
         covers = jnp.pad(covers, ((0, 0), (0, wp - w)))
+    ids = jnp.pad(seed_ids.astype(jnp.int32), ((0, 0), (0, cp - c)),
+                  constant_values=-1)[:, None, :]
     covers_out, seeds_out, counts_out = pl.pallas_call(
-        functools.partial(_stream_kernel, block_w=bw),
+        functools.partial(_stream_kernel, block_w=bw, c_chunk=c),
+        name="bucket_insert_stream",
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # ids [R, C]
+            pl.BlockSpec(memory_space=pl.ANY),        # ids [R, 1, Cp]
             pl.BlockSpec(memory_space=pltpu.VMEM),    # thresholds [B, 1]
             pl.BlockSpec(memory_space=pltpu.VMEM),    # counts in  [B, 1]
-            pl.BlockSpec(memory_space=pltpu.ANY),     # stream [R, C, Wp]
+            pl.BlockSpec(memory_space=pl.ANY),        # stream [R, Cs, Wp]
             pl.BlockSpec(memory_space=pltpu.VMEM),    # covers [B, Wp]
             pl.BlockSpec(memory_space=pltpu.VMEM),    # seeds  [B, k]
         ],
@@ -314,12 +327,11 @@ def bucket_insert_stream_pallas(seed_ids: jnp.ndarray, rows: jnp.ndarray,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, c, wp), rows.dtype),       # rows double buf
-            pltpu.SMEM((2, c), jnp.int32),            # ids double buf
+            pltpu.VMEM((2, cs, wp), rows.dtype),      # rows double buf
+            pltpu.SMEM((2, 1, cp), jnp.int32),        # ids double buf
             pltpu.SemaphoreType.DMA((2,)),            # rows sems
             pltpu.SemaphoreType.DMA((2,)),            # ids sems
         ],
         interpret=interpret,
-    )(seed_ids.astype(jnp.int32), thresholds[:, None],
-      counts[:, None], rows, covers, seeds)
+    )(ids, thresholds[:, None], counts[:, None], rows, covers, seeds)
     return covers_out[:, :w], counts_out[:, 0], seeds_out

@@ -21,8 +21,8 @@ greedy loop is resident in a single kernel:
     receiver;
   * each pick fuses the gain sweep (the shared ``gain_core`` AND-NOT +
     popcount tile body), the blockwise argmax, the winner-row
-    re-gather (one [1, W] DMA from HBM), the cover OR-update, and the
-    seed/gain/row writes.
+    re-gather (one 8-row block DMA from HBM), the cover OR-update,
+    and the seed/gain/row writes.
 
 Launch/HBM-traffic model per solve (k picks over [n, W] rows):
 
@@ -37,14 +37,15 @@ Launch/HBM-traffic model per solve (k picks over [n, W] rows):
             skewed gains; s = measured sweep fraction <= 1)
 
 Tie-break is bit-identical to ``jnp.argmax`` over the full masked
-gain vector: tiles are visited in ascending vertex order, jnp.argmax
-within a tile prefers the lowest index, and the cross-tile carry only
-replaces the incumbent on a strictly greater gain — so ties resolve
-to the globally lowest index, and all four solvers (scan / fused /
-resident / lazy) agree bit-for-bit on seeds, rows, covered, and
-gains.  The per-tile sweep body and the post-argmax commit are shared
-with the lazy kernel (``sweep_tile_argmax`` / ``commit_pick`` below)
-so the bit-exactness contract has exactly one implementation.
+gain vector: tiles are visited in ascending vertex order, the
+within-tile pick is the lowest index holding the tile maximum, and
+the cross-tile carry only replaces the incumbent on a strictly
+greater gain — so ties resolve to the globally lowest index, and
+all four solvers (scan / fused / resident / lazy) agree bit-for-bit
+on seeds, rows, covered, and gains.  The per-tile sweep body and the
+post-argmax commit are shared with the lazy kernel
+(``sweep_tile_argmax`` / ``commit_pick`` below) so the bit-exactness
+contract has exactly one implementation.
 """
 from __future__ import annotations
 
@@ -92,21 +93,37 @@ def sweep_tile_argmax(tile, covered, seeds, t, block_v: int):
     ridx_t = t * block_v + jax.lax.broadcasted_iota(
         jnp.int32, (block_v, 1), 0)
     taken = jnp.any(ridx_t == seeds, axis=1, keepdims=True)  # [BV, 1]
-    g = jnp.where(taken, -1, g)[:, 0]                      # [BV]
-    a = jnp.argmax(g)                    # lowest index within tile
-    return g[a], a.astype(jnp.int32)
+    g = jnp.where(taken, -1, g)                            # [BV, 1]
+    # argmax as max + smallest index holding it: Mosaic lowers argmax
+    # only for float32, and this keeps jnp.argmax's lowest-index rule.
+    best = jnp.max(g)
+    lidx = jax.lax.broadcasted_iota(jnp.int32, (block_v, 1), 0)
+    a = jnp.min(jnp.where(g == best, lidx, block_v))
+    return best, a
 
 
-def commit_pick(pick, best_gain, best_idx, winner_buf, covered_ref,
-                rows_out_ref, seeds_ref, gains_ref, lane_k):
+def commit_pick(pick, best_gain, best_idx, rows_hbm, winner_buf, win_sem,
+                covered_ref, rows_out_ref, seeds_ref, gains_ref, lane_k):
     """Fused post-argmax pick commit shared by the resident and lazy
-    kernels: a non-positive best gain is rejected (seed -1, gain 0,
-    no cover/row update — identical to ``jnp.argmax`` over an
-    all-masked vector), otherwise the re-gathered winner row ORs into
-    the cover and the seed/gain/row outputs are written in place."""
+    kernels: re-gather the winner row, then a non-positive best gain
+    is rejected (seed -1, gain 0, no cover/row update — identical to
+    ``jnp.argmax`` over an all-masked vector), otherwise the winner
+    row ORs into the cover and the seed/gain/row outputs are written
+    in place.
+
+    The re-gather DMAs the SUBLANE-aligned [8, Wp] block holding the
+    winner (a one-row HBM slice is refused by Mosaic's (8, 128)
+    tiling) and selects the row inside it; ``n_pad`` is a multiple
+    of SUBLANE, so the block never runs past the rows."""
+    sub = winner_buf.shape[0]
+    base = pl.multiple_of((best_idx // sub) * sub, sub)
+    win = pltpu.make_async_copy(rows_hbm.at[pl.ds(base, sub)],
+                                winner_buf, win_sem)
+    win.start()
+    win.wait()
+    winner = winner_buf[pl.ds(best_idx - base, 1), :]      # [1, Wp]
     take = best_gain > 0
-    row = jnp.where(take, winner_buf[...],
-                    jnp.zeros_like(winner_buf[...]))       # [1, Wp]
+    row = jnp.where(take, winner, jnp.zeros_like(winner))
     covered_ref[...] = covered_ref[...] | row
     rows_out_ref[pl.ds(pick, 1), :] = row
     hit = lane_k == pick
@@ -131,7 +148,7 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
     covered_ref uint32 [1, Wp]      VMEM out (running union)
     gains_ref   int32  [1, k]       VMEM out
     tile_buf    uint32 [2, BV, Wp]  double-buffered row-tile scratch
-    winner_buf  uint32 [1, Wp]      winner re-gather scratch
+    winner_buf  uint32 [8, Wp]      winner-block re-gather scratch
 
     Zero-padded rows need no masking: their gain is 0, so with any
     positive gain left they lose the argmax, at equal gain 0 the
@@ -179,15 +196,10 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
         best_gain, best_idx = jax.lax.fori_loop(
             0, num_tiles, tile_body, (jnp.int32(-1), jnp.int32(0)))
 
-        # --- winner re-gather: one [1, Wp] row DMA from HBM ---------
-        win = pltpu.make_async_copy(rows_hbm.at[pl.ds(best_idx, 1)],
-                                    winner_buf, win_sem)
-        win.start()
-        win.wait()
-
-        # --- fused update: cover OR, seed/gain/row writes -----------
-        commit_pick(pick, best_gain, best_idx, winner_buf, covered_ref,
-                    rows_out_ref, seeds_ref, gains_ref, lane_k)
+        # --- winner re-gather + cover OR, seed/gain/row writes ------
+        commit_pick(pick, best_gain, best_idx, rows_hbm, winner_buf,
+                    win_sem, covered_ref, rows_out_ref, seeds_ref,
+                    gains_ref, lane_k)
         return 0
 
     jax.lax.fori_loop(0, k, pick_body, 0)
@@ -234,7 +246,8 @@ def greedy_maxcover_resident_pallas(rows: jnp.ndarray, k: int,
         rows = jnp.pad(rows, ((0, n_pad - n), (0, wp - w)))
     seeds, sel_rows, covered, gains = pl.pallas_call(
         functools.partial(_kernel, block_v=bv),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        name="greedy_pick_resident",
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -250,7 +263,7 @@ def greedy_maxcover_resident_pallas(rows: jnp.ndarray, k: int,
         ],
         scratch_shapes=[
             pltpu.VMEM((2, bv, wp), rows.dtype),   # row-tile double buf
-            pltpu.VMEM((1, wp), rows.dtype),       # winner re-gather
+            pltpu.VMEM((gain_core.SUBLANE, wp), rows.dtype),  # winner block
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA(()),
         ],

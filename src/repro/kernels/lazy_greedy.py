@@ -11,7 +11,7 @@ submodularity), so anything whose bound cannot beat the running best
 need not be re-evaluated.  This kernel is the TPU analogue at tile
 granularity:
 
-  * a [num_tiles] stale-upper-bound vector lives in VMEM for the
+  * a [num_tiles] stale-upper-bound vector lives in SMEM for the
     whole solve; entry t holds the masked gain maximum of tile t as
     of the last time the tile was swept (init: +inf, so pick 0 sweeps
     everything);
@@ -27,13 +27,11 @@ granularity:
     ``greedy_pick.sweep_tile_argmax`` / ``greedy_pick.commit_pick``,
     so the bit-exactness contract has one implementation.
 
-Mosaic caveat: the skip decision reads (and the sweep writes) the
-bound vector at a dynamic tile index — ``ub_ref[0, t]`` with a traced
-``t``.  The interpret path (this container's validation mode) handles
-that directly; if real-TPU lowering rejects the dynamic VMEM lane
-access, the bounds belong in SMEM like ``best_ref``/``cnt_ref``
-(an int32 [num_tiles] vector is tiny either way — the ROADMAP TPU
-timing item covers validating this choice on hardware).
+The skip decision reads (and the sweep writes) the bound vector at a
+dynamic tile index — ``ub_ref[t]`` with a traced ``t``.  Mosaic
+refuses scalar stores to VMEM, so the bounds live in SMEM like
+``best_ref``/``cnt_ref`` (an int32 [num_tiles] vector: 8 KiB at
+n=2^18 with 128-row tiles).
 
 Tie-break stays bit-identical to ``jnp.argmax`` over the full masked
 gain vector.  The skip rule is *strict less-than*: a tile whose bound
@@ -127,12 +125,12 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
     covered_ref uint32 [1, Wp]      VMEM out (running union)
     gains_ref   int32  [1, k]       VMEM out
     swept_ref   int32  [1, 1]       VMEM out (tiles swept, all picks)
-    ub_ref      int32  [1, Tp]      VMEM scratch — stale per-tile
-                                    upper bounds (T tiles, lane-padded)
+    ub_ref      int32  [T]          SMEM scratch — stale per-tile
+                                    upper bounds (T tiles)
     best_ref    int32  [1, 2]       SMEM scratch — running (gain, idx)
     cnt_ref     int32  [1, 1]       SMEM scratch — tiles-swept counter
     tile_buf    uint32 [2, BV, Wp]  double-buffered row-tile scratch
-    winner_buf  uint32 [1, Wp]      winner re-gather scratch
+    winner_buf  uint32 [8, Wp]      winner-block re-gather scratch
 
     The running best lives in SMEM (not the fori carry) because the
     sweep happens under ``pl.when`` — a skipped tile must leave it
@@ -146,7 +144,12 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
     seeds_ref[...] = jnp.full_like(seeds_ref, -1)
     gains_ref[...] = jnp.zeros_like(gains_ref)
     rows_out_ref[...] = jnp.zeros_like(rows_out_ref)
-    ub_ref[...] = jnp.full_like(ub_ref, _UB_INIT)
+
+    def init_bound(t, _):
+        ub_ref[t] = jnp.int32(_UB_INIT)
+        return 0
+
+    jax.lax.fori_loop(0, num_tiles, init_bound, 0)
     cnt_ref[0, 0] = jnp.int32(0)
     lane_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
@@ -163,7 +166,7 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
         # bounds are masked maxima >= -1, so tile 0 always sweeps —
         # the same "first unskipped tile seeds the carry" behaviour
         # as the full sweep).
-        d0 = ub_ref[0, 0] >= best_ref[0, 0]
+        d0 = ub_ref[0] >= best_ref[0, 0]
 
         @pl.when(d0)
         def _warmup():
@@ -178,7 +181,7 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
             bg_pre = best_ref[0, 0]
             t_nxt = jnp.minimum(t + 1, num_tiles - 1)
             d_next = jnp.logical_and(t + 1 < num_tiles,
-                                     ub_ref[0, t_nxt] >= bg_pre)
+                                     ub_ref[t_nxt] >= bg_pre)
             nslot = jnp.where(d_cur, 1 - slot, slot)
 
             @pl.when(d_next)
@@ -195,7 +198,7 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
                     t, block_v)
                 # Refresh the stale bound: the fresh masked max upper-
                 # bounds every later pick's masked max of this tile.
-                ub_ref[0, t] = ga
+                ub_ref[t] = ga
                 bg = best_ref[0, 0]
                 better = ga > bg             # strict: keep lowest tile
                 best_ref[0, 0] = jnp.where(better, ga, bg)
@@ -209,16 +212,11 @@ def _kernel(rows_hbm, excl_ref, seeds_ref, rows_out_ref, covered_ref,
         best_gain = best_ref[0, 0]
         best_idx = best_ref[0, 1]
 
-        # --- winner re-gather: one [1, Wp] row DMA from HBM ---------
-        win = pltpu.make_async_copy(rows_hbm.at[pl.ds(best_idx, 1)],
-                                    winner_buf, win_sem)
-        win.start()
-        win.wait()
-
-        # --- fused update: cover OR, seed/gain/row writes -----------
-        greedy_pick.commit_pick(pick, best_gain, best_idx, winner_buf,
-                                covered_ref, rows_out_ref, seeds_ref,
-                                gains_ref, lane_k)
+        # --- winner re-gather + cover OR, seed/gain/row writes ------
+        greedy_pick.commit_pick(pick, best_gain, best_idx, rows_hbm,
+                                winner_buf, win_sem, covered_ref,
+                                rows_out_ref, seeds_ref, gains_ref,
+                                lane_k)
         return 0
 
     jax.lax.fori_loop(0, k, pick_body, 0)
@@ -265,10 +263,10 @@ def greedy_maxcover_lazy_pallas(rows: jnp.ndarray, k: int,
     if n_pad != n or wp != w:
         rows = jnp.pad(rows, ((0, n_pad - n), (0, wp - w)))
     num_tiles = n_pad // bv
-    tp = gain_core.padded_size(num_tiles, gain_core.LANE)
     seeds, sel_rows, covered, gains, swept = pl.pallas_call(
         functools.partial(_kernel, block_v=bv),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        name="lazy_greedy",
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -285,11 +283,11 @@ def greedy_maxcover_lazy_pallas(rows: jnp.ndarray, k: int,
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, tp), jnp.int32),        # stale upper bounds
+            pltpu.SMEM((num_tiles,), jnp.int32),   # stale upper bounds
             pltpu.SMEM((1, 2), jnp.int32),         # running (gain, idx)
             pltpu.SMEM((1, 1), jnp.int32),         # tiles-swept counter
             pltpu.VMEM((2, bv, wp), rows.dtype),   # row-tile double buf
-            pltpu.VMEM((1, wp), rows.dtype),       # winner re-gather
+            pltpu.VMEM((gain_core.SUBLANE, wp), rows.dtype),  # winner block
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA(()),
         ],

@@ -321,8 +321,8 @@ def rrr_expand_step_pallas(frontier: jnp.ndarray, visited: jnp.ndarray,
         functools.partial(_kernel, block_v=bv, d_tile=dt,
                           num_d_tiles=nd, w=w),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
@@ -405,8 +405,8 @@ def rrr_expand_step_resident_pallas(frontier: jnp.ndarray,
     newf, viso = pl.pallas_call(
         functools.partial(_kernel_resident, block_v=bv, num_d_tiles=nd),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -39,7 +39,6 @@ from repro.configs import ARCHS, get_config
 from repro.configs.shapes import SHAPES, applicable
 from repro.distributed import hlo_analysis as hlo
 from repro.launch import mesh as mesh_lib
-from repro.runtime.jaxcompat import set_mesh
 from repro.launch import specs as specs_lib
 from repro.models import model as model_lib
 from repro.models import transformer as tfm
@@ -68,7 +67,7 @@ def lower_cell(arch: str, shape: str, multi_pod: bool, *,
     dp_size = int(np.prod([mesh.shape[a] for a in bundle.rules["dp"]]))
     bundle.rules = dict(bundle.rules)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if cell.kind == "train":
             state_sds, specs = specs_lib.state_shapes(cfg, opt_cfg)
             state_ps = bundle.state_pspecs(specs)
@@ -259,7 +258,7 @@ def run_im_cell(multi_pod: bool, *, n: int = 4_800_000, theta: int = 1 << 20,
     key = sds((2,), jnp.uint32)
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if baseline:
             fn, _ = greediris.build_ripples_round(
                 mesh, axes, n=n, theta=theta, k=k, sample_chunks=8,

@@ -25,6 +25,7 @@ from repro.graphs import generators
 from repro.graphs.csr import padded_adjacency, padded_forward_adjacency
 from repro.launch.mesh import make_host_mesh
 from repro.runtime import faults
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def _coin_chunk_arg(text: str) -> int:
@@ -95,6 +96,7 @@ def make_graph(kind: str, n: int, avg_deg: float, seed: int):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="er", choices=("er", "ba", "rmat"))
     ap.add_argument("--n", type=int, default=2000)

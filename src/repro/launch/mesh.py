@@ -1,20 +1,24 @@
 """Production mesh builders (TPU v5e pods; CPU placeholder devices for
 the dry-run).  Functions, not module constants, so importing never
-touches jax device state.  Mesh construction goes through
-``repro.runtime.jaxcompat`` so the same code runs on jax versions with
-and without ``AxisType`` / ``set_mesh``."""
+touches jax device state."""
 from __future__ import annotations
 
 import jax
 
-from repro.runtime.jaxcompat import make_mesh
+
+def _auto_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (shardings
+    are propagated, not carried in types)."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_im_mesh(num_machines: int, *, multi_pod: bool = False):
@@ -23,11 +27,11 @@ def make_im_mesh(num_machines: int, *, multi_pod: bool = False):
     multi_pod the same chips are named ('pod', 'machines') so the
     all_to_all/gather spans both axes explicitly."""
     if multi_pod:
-        return make_mesh((2, num_machines // 2), ("pod", "machines"))
-    return make_mesh((num_machines,), ("machines",))
+        return _auto_mesh((2, num_machines // 2), ("pod", "machines"))
+    return _auto_mesh((num_machines,), ("machines",))
 
 
 def make_host_mesh():
     """Whatever devices exist right now, as a 1-D mesh (CPU tests)."""
     n = len(jax.devices())
-    return make_mesh((n,), ("machines",))
+    return _auto_mesh((n,), ("machines",))

@@ -46,6 +46,7 @@ from repro.core.service import (InfluenceService, Query,
                                 snapshot_pool)
 from repro.launch.im_driver import make_graph
 from repro.runtime import faults
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.faults import FaultPlan, InjectedFault
 
 
@@ -246,6 +247,7 @@ def answers_equal(a, b) -> bool:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="er", choices=("er", "ba", "rmat"))
     ap.add_argument("--n", type=int, default=256)

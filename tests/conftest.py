@@ -13,8 +13,11 @@ sys.path.insert(0, REPO)
 
 def run_with_devices(code: str, num_devices: int = 8, timeout: int = 560):
     """Run a python snippet in a subprocess with N fake host devices
-    (the main test process must keep the default 1-device world)."""
+    (the main test process must keep the default 1-device world).  The
+    child is held to the CPU, so it never tries to take a chip that
+    its parent may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={num_devices}")
     env["PYTHONPATH"] = SRC

@@ -55,7 +55,7 @@ def test_loop_hidden_launch_caught_by_launch_context_only():
 def test_f64_leak_caught_by_dtype_whitelist_only():
     c = _fixture_contract(bad_kernels.f64_leak,
                           dtype_whitelist=frozenset({"float32"}))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         report = contracts.run_contract(c, skip_hlo=True)
     (violation,) = report.violations
     assert violation.rule == "dtype-whitelist"

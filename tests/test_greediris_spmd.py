@@ -8,11 +8,11 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.graphs import generators
 from repro.graphs.csr import padded_adjacency
 from repro.core import greediris, maxcover
-from repro.runtime.jaxcompat import make_mesh
+from repro.launch.mesh import make_im_mesh
 g = generators.erdos_renyi(200, 8.0, seed=1)
 nbr, prob, wt = padded_adjacency(g)
 key = jax.random.key(0)
-mesh = make_mesh((8,), ("machines",))
+mesh = make_im_mesh(8)
 """
 
 
@@ -86,10 +86,10 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.graphs import generators
 from repro.graphs.csr import padded_adjacency
 from repro.core import greediris
-from repro.runtime.jaxcompat import make_mesh
+from repro.launch.mesh import make_im_mesh
 g = generators.erdos_renyi(128, 6.0, seed=2)
 nbr, prob, wt = padded_adjacency(g)
-mesh = make_mesh((2, 4), ("pod", "machines"))
+mesh = make_im_mesh(8, multi_pod=True)
 fn, _, _ = greediris.build_round(
     mesh, ("pod", "machines"), n=128, theta=256, k=4,
     max_degree=g.max_in_degree())
@@ -107,13 +107,13 @@ from repro.configs import get_config
 from repro.models import model as model_lib
 from repro.launch import specs as specs_lib
 from repro.optim import adamw
-from repro.runtime.jaxcompat import make_mesh, set_mesh
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_config("qwen3-moe-235b-a22b", smoke=True)
 opt = adamw.OptConfig(warmup_steps=1, total_steps=4)
 bundle = model_lib.build(cfg, opt)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     state, specs = bundle.init_state(jax.random.key(0))
     sps = model_lib.concretize_pspecs(
         bundle.state_pspecs(specs), jax.eval_shape(lambda: state), mesh)
@@ -261,7 +261,7 @@ def test_gather_receiver_issues_one_stream_call(monkeypatch):
     from repro.graphs import generators
     from repro.graphs.csr import padded_adjacency
     from repro.kernels import ops
-    from repro.runtime.jaxcompat import make_mesh
+    from repro.launch.mesh import make_im_mesh
 
     calls = {"stream": 0, "chunk": 0}
     real_stream = ops.bucket_insert_stream
@@ -280,7 +280,7 @@ def test_gather_receiver_issues_one_stream_call(monkeypatch):
 
     g = generators.erdos_renyi(64, 6.0, seed=3)
     nbr, prob, wt = padded_adjacency(g)
-    mesh = make_mesh((1,), ("machines",))
+    mesh = make_im_mesh(1)
     # odd sizes -> insert_stream's jit cache cannot have this trace yet
     fn, _, _ = greediris.build_round(
         mesh, ("machines",), n=64, theta=96, k=3,
@@ -334,9 +334,15 @@ def test_survivors_mask_on_mesh():
         o = jax.jit(fn_d)(nbr, prob, wt, key)
         seeds = np.asarray(o.seeds)
         valid = seeds[seeds >= 0]
-        # the dead machine's vertex partition contributes no seeds
-        shard = 200 // 8 + (1 if 200 % 8 else 0)
-        dead = set(range(drop * shard, min((drop + 1) * shard, 200)))
+        # the dead machine's vertex partition contributes no seeds;
+        # build_round assigns machine j the slice j of a keyed vertex
+        # permutation, so read the dead partition from that same perm
+        n_pad = 200 + (-200) % 8
+        per = n_pad // 8
+        perm = np.asarray(jax.random.permutation(
+            jax.random.fold_in(key, 0x9E37), n_pad))
+        dead = {int(v) for v in perm[drop * per:(drop + 1) * per]
+                if v < 200}
         assert not (set(valid.tolist()) & dead), (valid, drop)
         assert len(set(valid.tolist())) == len(valid)
         assert int(o.coverage) > 0
