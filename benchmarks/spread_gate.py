@@ -25,9 +25,7 @@ Run directly (exits 1 on any gate failure)::
 
     PYTHONPATH=src python -m benchmarks.spread_gate --fast
 
-or via the bench suite: ``kernels_bench`` times one gate pass as a CI
-row, so a quality regression fails the bench job exactly like a perf
-regression.
+The CI bench job runs it, so a quality regression fails that job.
 """
 from __future__ import annotations
 
@@ -154,8 +152,7 @@ def run_gate(*, n: int = 512, avg_deg: float = 6.0, ks=(4, 8, 16),
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
-                    help="CI-sized sweep (matches the kernels_bench "
-                         "spread-gate row)")
+                    help="CI-sized sweep")
     ap.add_argument("--json", default=None, metavar="OUT",
                     help="write per-variant rows to OUT as JSON")
     ap.add_argument("--z", type=float, default=Z_MAX,

@@ -83,10 +83,45 @@ class GreediRISOut(NamedTuple):
     coverage: jnp.ndarray       # int32 [] coverage of returned seeds
     global_coverage: jnp.ndarray   # best streaming-receiver coverage
     best_local_coverage: jnp.ndarray
+    # Counters of the round's work, int32 [], summed over sample chunks
+    # and machines (denominators: ``RoundUnits``):
+    bfs_steps: jnp.ndarray      # S1 BFS steps taken
+    rrr_pairs: jnp.ndarray      # S1 (sample, vertex) pairs sampled
+    sender_tiles_swept: jnp.ndarray   # S3 row tiles the picks swept
+
+
+class RoundUnits(NamedTuple):
+    """Static sizes of a round's work, the denominators of its
+    counters: ``build_round(...)[0].units``, or ``round_units`` with
+    the same arguments."""
+    coins_per_bfs_step: int     # uniforms one S1 BFS step draws
+    sender_picks: int           # S3 picks, over all machines
+    sender_tiles_per_pick: int  # row tiles one full S3 pick sweeps
 
 
 def _axis_size(mesh, axes: Sequence[str]) -> int:
     return int(math.prod(mesh.shape[a] for a in axes))
+
+
+def _round_sizes(n: int, theta: int, m: int):
+    """(n_pad, rows per machine, sets per machine) of a round."""
+    n_pad = ((n + m - 1) // m) * m
+    return n_pad, n_pad // m, ((theta // m + 31) // 32) * 32
+
+
+def round_units(*, n: int, theta: int, k: int, max_degree: int,
+                machines: int, model: str = "IC", sample_chunks: int = 1,
+                coin_chunk: int = 32) -> RoundUnits:
+    """The ``units`` of ``build_round`` on ``machines`` machines with
+    these arguments."""
+    from repro.core.rrr import coins_per_step
+    _, per, theta_local = _round_sizes(n, theta, machines)
+    return RoundUnits(
+        coins_per_bfs_step=coins_per_step(
+            n, max_degree, theta_local // sample_chunks, model=model,
+            coin_chunk=coin_chunk),
+        sender_picks=machines * k,
+        sender_tiles_per_pick=maxcover.full_sweep_tiles(per, 1))
 
 
 def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
@@ -103,10 +138,12 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                 survivors=None):
     """Build the jittable distributed round fn(nbr, prob, wt, key).
 
-    The graph (padded reverse adjacency [n_pad, d]) is replicated on
-    every device — the paper's setup ("the input graph is loaded on all
-    machines").  Returns a function suitable for jax.jit with the given
-    mesh, and the padded vertex count.
+    The graph (padded reverse adjacency [n, max_degree]) is replicated
+    on every device — the paper's setup ("the input graph is loaded on
+    all machines").  Returns a function suitable for jax.jit with the
+    given mesh, the padded vertex count and the rounded theta.  The
+    function's ``units`` attribute (:class:`RoundUnits`) holds the
+    denominators of the counters its :class:`GreediRISOut` carries.
 
     solver: S3 sender path — "scan" | "fused" | "resident" | "lazy"
     (see the module docstring; all bit-identical).  None defaults from the
@@ -205,7 +242,7 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
     # it keeps kernelizing the S4 receiver either way.
     solver = maxcover.resolve_solver(solver, use_kernel or None)
     from repro.core.randgreedi import _normalize_survivors
-    from repro.core.rrr import (rrr_batch, rrr_batch_packed,
+    from repro.core.rrr import (_rrr_batch_dense, _rrr_batch_packed,
                                 resolve_sampler)
     from repro.kernels import vmem_budget
     if gather not in vmem_budget.GATHER_MODES:
@@ -223,9 +260,7 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
     axes = tuple(axes)
     m = _axis_size(mesh, axes)
     survivors = _normalize_survivors(survivors, m)
-    n_pad = ((n + m - 1) // m) * m
-    per = n_pad // m
-    theta_local = ((theta // m + 31) // 32) * 32
+    n_pad, per, theta_local = _round_sizes(n, theta, m)
     assert theta_local % sample_chunks == 0 or sample_chunks == 1
     w_local = theta_local // 32
     w_global = (theta_local * m) // 32
@@ -243,21 +278,32 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
             streaming.num_buckets(k, delta), w_global, k, total=m * kk)
     # sparse-shuffle bucket capacity: pairs per (src, dst) pair
     cap = max(64, int(2.0 * theta_local * est_rrr_len / m))
+    units = round_units(n=n, theta=theta, k=k, max_degree=max_degree,
+                        machines=m, model=model,
+                        sample_chunks=sample_chunks, coin_chunk=coin_chunk)
+
+    def sample_dense(nbr, prob, wt, roots, kb):
+        """One S1 batch under the dense sampler: (bool [b, n], steps)."""
+        return _rrr_batch_dense(nbr, prob, wt, roots, kb, model=model,
+                                max_steps=max_steps, coin_chunk=coin_chunk)
 
     def sample_packed(nbr, prob, wt, roots, kb):
-        """One S1 batch as packed words [n, b/32] under the sampler."""
+        """One S1 batch as (packed words [n, b/32], steps)."""
         if sampler == "dense":
-            vis = rrr_batch(nbr, prob, wt, roots, kb, model=model,
-                            max_steps=max_steps,
-                            coin_chunk=coin_chunk)         # [b, n]
-            return bitset.pack_bool_matrix(vis.T)          # [n, b/32]
-        return rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot,
-                                roots, kb, model=model,
-                                max_steps=max_steps,
-                                coin_chunk=coin_chunk, expand=expand,
-                                gather=gather, block_v=block_v)
+            vis, steps = sample_dense(nbr, prob, wt, roots, kb)
+            return bitset.pack_bool_matrix(vis.T), steps   # [n, b/32]
+        return _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot,
+                                 roots, kb, model=model,
+                                 max_steps=max_steps,
+                                 coin_chunk=coin_chunk,
+                                 kernel=(expand == "kernel"),
+                                 gather=gather, block_v=block_v)
 
     def shard_fn(nbr, prob, wt, key):
+        if nbr.shape[1] != max_degree:
+            raise ValueError(f"the adjacency has {nbr.shape[1]} slots per "
+                             f"vertex; build_round was given max_degree="
+                             f"{max_degree}")
         pid = lax.axis_index(axes)
         key_p = jax.random.fold_in(key, pid)
         perm = jax.random.permutation(
@@ -266,18 +312,21 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
 
         if shuffle == "dense":
             # --- S1: sample theta/m RRR sets, packed bitmatrix ---
-            def one_chunk(i, acc):
+            def one_chunk(i, state):
+                acc, steps = state
                 kc = jax.random.fold_in(key_p, i)
                 kr, kb = jax.random.split(kc)
                 b = theta_local // sample_chunks
                 roots = jax.random.randint(kr, (b,), 0, n)
-                packed = sample_packed(nbr, prob, wt, roots, kb)
-                return lax.dynamic_update_slice(
-                    acc, packed, (0, i * (b // 32)))
+                packed, st = sample_packed(nbr, prob, wt, roots, kb)
+                return (lax.dynamic_update_slice(
+                    acc, packed, (0, i * (b // 32))), steps + st)
 
             x_p = jnp.zeros((nbr.shape[0], w_local),
                             dtype=bitset.WORD_DTYPE)
-            x_p = lax.fori_loop(0, sample_chunks, one_chunk, x_p)
+            x_p, bfs_steps = lax.fori_loop(0, sample_chunks, one_chunk,
+                                           (x_p, jnp.int32(0)))
+            rrr_pairs = jnp.sum(bitset.popcount(x_p))
             if nbr.shape[0] < n_pad:
                 x_p = jnp.pad(x_p, ((0, n_pad - nbr.shape[0]), (0, 0)))
             # --- S2: uniform random partition + dense all-to-all ---
@@ -289,25 +338,27 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
             counts = jnp.zeros((m,), dtype=jnp.int32)
 
             def one_chunk(i, state):
-                send, counts = state
+                send, counts, steps, rrr_pairs = state
                 kc = jax.random.fold_in(key_p, i)
                 kr, kb = jax.random.split(kc)
                 b = theta_local // sample_chunks
                 roots = jax.random.randint(kr, (b,), 0, n)
                 size = cap * m // sample_chunks
                 if sampler == "dense":
-                    vis = rrr_batch(nbr, prob, wt, roots, kb,
-                                    model=model, max_steps=max_steps,
-                                    coin_chunk=coin_chunk)  # [b, n]
+                    vis, st = sample_dense(nbr, prob, wt, roots, kb)
                     s_idx, v_idx = jnp.nonzero(vis, size=size,
                                                fill_value=-1)
+                    rrr_pairs = rrr_pairs + jnp.sum(vis, dtype=jnp.int32)
                 else:
                     # packed samplers feed the COO exchange through a
                     # word-iterating nonzero — the [b, n] bool matrix
                     # never materializes.
-                    packed = sample_packed(nbr, prob, wt, roots, kb)
+                    packed, st = sample_packed(nbr, prob, wt, roots, kb)
                     s_idx, v_idx = bitset.packed_nonzero(
                         packed, size=size, fill_value=-1)
+                    rrr_pairs = rrr_pairs + jnp.sum(
+                        bitset.popcount(packed))
+                steps = steps + st
                 valid = s_idx >= 0
                 sample_gid = pid * theta_local + i * b + s_idx
                 pos = inv_perm[jnp.clip(v_idx, 0)]
@@ -324,12 +375,13 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                 send = send.at[d_c, s_c, 1].set(sample_gid, mode="drop")
                 counts = counts + jnp.sum(
                     onehot * ok[:, None].astype(jnp.int32), axis=0)
-                return send, counts
+                return send, counts, steps, rrr_pairs
 
             # mark empty slots with sample id -1
             send = send.at[:, :, 1].set(-1)
-            send, counts = lax.fori_loop(0, sample_chunks, one_chunk,
-                                         (send, counts))
+            send, counts, bfs_steps, rrr_pairs = lax.fori_loop(
+                0, sample_chunks, one_chunk,
+                (send, counts, jnp.int32(0), jnp.int32(0)))
             recv = lax.all_to_all(send, axes, split_axis=0,
                                   concat_axis=0, tiled=True)
             # rebuild packed rows [per, W_global]; each (v, s) pair is
@@ -446,12 +498,16 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
         take_global = g_cov_best >= lc_all[l_best]
         seeds = jnp.where(take_global, g_seeds_best, lids_all[l_best])
         cov = jnp.maximum(g_cov_best, lc_all[l_best])
-        return GreediRISOut(seeds, cov, g_cov_best, lc_all[l_best])
+        return GreediRISOut(seeds, cov, g_cov_best, lc_all[l_best],
+                            lax.psum(bfs_steps, axes),
+                            lax.psum(rrr_pairs, axes),
+                            lax.psum(sol.tiles_swept, axes))
 
     specs_in = (P(), P(), P(), P())  # graph + key replicated
-    specs_out = GreediRISOut(P(), P(), P(), P())
+    specs_out = GreediRISOut(*[P()] * len(GreediRISOut._fields))
     fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=specs_in,
                        out_specs=specs_out, check_vma=False)
+    fn.units = units
     return fn, n_pad, theta_local * m
 
 

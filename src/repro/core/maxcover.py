@@ -54,6 +54,7 @@ class CoverSolution(NamedTuple):
     covered: jnp.ndarray    # uint32 [W] union of selected rows
     coverage: jnp.ndarray   # int32 [] total bits covered
     gains: jnp.ndarray      # int32 [k] marginal gain at each pick
+    tiles_swept: jnp.ndarray  # int32 [] row tiles the picks swept
 
 
 def resolve_solver(solver: str | None,
@@ -143,27 +144,35 @@ def _greedy_maxcover_batch(rows: jnp.ndarray, excluded: jnp.ndarray,
     return jax.vmap(lambda ex: _solve_one(rows, ex, k, solver))(excluded)
 
 
+def full_sweep_tiles(n: int, k: int) -> int:
+    """Row tiles k picks sweep when none is skipped: k times the lazy
+    kernel's row tiles of an [n, W] pool (``lazy_greedy.num_row_tiles``).
+    The ``tiles_swept`` of every solver but "lazy"."""
+    from repro.kernels.lazy_greedy import num_row_tiles
+    return k * num_row_tiles(n)
+
+
 def _solve_one(rows: jnp.ndarray, excluded: jnp.ndarray, k: int,
                solver: str) -> CoverSolution:
     """One greedy solve (trace-level body — vmapped by the batch entry
     point, so everything here must be vmap-compatible)."""
     n, w = rows.shape
+    full = jnp.int32(full_sweep_tiles(n, k))
 
     if solver == "resident":
         from repro.kernels import ops as kops
         seeds, sel_rows, covered, gains = kops.greedy_maxcover_resident(
             rows, k, excluded)
         return CoverSolution(seeds, sel_rows, covered,
-                             bitset.coverage_size(covered), gains)
+                             bitset.coverage_size(covered), gains, full)
 
     if solver == "lazy":
         from repro.kernels import ops as kops
-        # The tiles-swept diagnostic is dropped here (CoverSolution is
-        # solver-agnostic); benchmarks read it off the kernel wrapper.
-        seeds, sel_rows, covered, gains, _ = kops.greedy_maxcover_lazy(
+        # The kernel's own count of the tiles its picks re-swept.
+        seeds, sel_rows, covered, gains, swept = kops.greedy_maxcover_lazy(
             rows, k, excluded)
         return CoverSolution(seeds, sel_rows, covered,
-                             bitset.coverage_size(covered), gains)
+                             bitset.coverage_size(covered), gains, swept)
 
     if solver == "fused":
         from repro.kernels import ops as kops
@@ -201,7 +210,7 @@ def _solve_one(rows: jnp.ndarray, excluded: jnp.ndarray, k: int,
     covered, seeds, sel_rows, picked, gains = jax.lax.fori_loop(
         0, k, body, (covered, seeds, sel_rows, picked, gains))
     return CoverSolution(seeds, sel_rows, covered,
-                         bitset.coverage_size(covered), gains)
+                         bitset.coverage_size(covered), gains, full)
 
 
 def _popcount_words(words) -> int:

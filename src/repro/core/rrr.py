@@ -112,6 +112,19 @@ def _coin_chunks(d: int, coin_chunk: int) -> Tuple[int, int, int]:
     return chunk, n_chunks, n_chunks * chunk
 
 
+def coins_per_step(n: int, d: int, batch: int, *, model: str,
+                   coin_chunk: int) -> int:
+    """Uniforms every sampler draws in one BFS step of a batch, whatever
+    its frontier holds: under IC one per (sample, vertex, edge slot) of
+    the degree-chunked ``[batch, n, d_pad]`` coin plane, under LT one
+    per (sample, vertex)."""
+    if d == 0:
+        return 0
+    if model == "IC":
+        return batch * n * _coin_chunks(d, coin_chunk)[2]
+    return batch * n
+
+
 @functools.partial(
     jax.jit, static_argnames=("model", "max_steps", "sampler", "coin_chunk",
                               "gather", "block_v"))
@@ -138,18 +151,24 @@ def rrr_batch(nbr, prob, wt, roots, key, *, model: str, max_steps: int = 64,
     sampler = resolve_sampler(sampler)
     if sampler != "dense":
         fwd_nbr, fwd_rslot = _require_fwd(fwd, sampler)
-        packed = _rrr_batch_packed(
+        packed, _ = _rrr_batch_packed(
             nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, model=model,
             max_steps=max_steps, coin_chunk=coin_chunk,
             kernel=(sampler == "kernel"), gather=gather, block_v=block_v)
         return bitset.unpack_words(packed, roots.shape[0]).T
+    return _rrr_batch_dense(nbr, prob, wt, roots, key, model=model,
+                            max_steps=max_steps, coin_chunk=coin_chunk)[0]
 
+
+def _rrr_batch_dense(nbr, prob, wt, roots, key, *, model: str,
+                     max_steps: int, coin_chunk: int):
+    """The dense BFS engine: (visited bool [batch, n], BFS steps)."""
     n, d = nbr.shape
     batch = roots.shape[0]
     visited0 = jnp.zeros((batch, n), dtype=bool).at[
         jnp.arange(batch), roots].set(True)
     if d == 0:          # edgeless graph: RRR(root) = {root}
-        return visited0
+        return visited0, jnp.int32(0)
 
     valid = nbr >= 0
 
@@ -213,9 +232,9 @@ def rrr_batch(nbr, prob, wt, roots, key, *, model: str, max_steps: int = 64,
         frontier, _, _, step = state
         return jnp.any(frontier) & (step < max_steps)
 
-    _, visited, _, _ = jax.lax.while_loop(
-        cond, body, (visited0, visited0, key, 0))
-    return visited
+    _, visited, _, steps = jax.lax.while_loop(
+        cond, body, (visited0, visited0, key, jnp.int32(0)))
+    return visited, steps
 
 
 def _packed_roots(roots, n: int):
@@ -293,12 +312,13 @@ def _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
                       model: str, max_steps: int, coin_chunk: int,
                       kernel: bool, gather: str = "auto",
                       block_v: Optional[int] = None):
-    """The packed BFS engine shared by sampler="packed" and "kernel"."""
+    """The packed BFS engine shared by sampler="packed" and "kernel":
+    (visited uint32 [n, W], BFS steps taken)."""
     n, d = nbr.shape
     batch = roots.shape[0]
     visited0 = _packed_roots(roots, n)
     if d == 0:          # edgeless graph: RRR(root) = {root}
-        return visited0
+        return visited0, jnp.int32(0)
     valid = nbr >= 0
     chunk, n_chunks, d_pad = _coin_chunks(d, coin_chunk)
 
@@ -357,9 +377,9 @@ def _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
         frontier, _, _, step = state
         return jnp.any(frontier) & (step < max_steps)
 
-    _, visited, _, _ = jax.lax.while_loop(
-        cond, body, (visited0, visited0, key, 0))
-    return visited
+    _, visited, _, steps = jax.lax.while_loop(
+        cond, body, (visited0, visited0, key, jnp.int32(0)))
+    return visited, steps
 
 
 @functools.partial(
@@ -391,7 +411,7 @@ def rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
                              key, model=model, max_steps=max_steps,
                              coin_chunk=coin_chunk,
                              kernel=(expand == "kernel"),
-                             gather=gather, block_v=block_v)
+                             gather=gather, block_v=block_v)[0]
 
 
 @functools.partial(jax.jit,

@@ -66,6 +66,7 @@ from repro.core.rrr import SAMPLERS as _SAMPLERS
 from repro.core.rrr import resolve_sampler, sample_incidence
 from repro.runtime.faults import (FaultPlan, InjectedFault,
                                   fire as _fire_fault)
+from repro.runtime.spans import span
 
 
 # Static contract (proved by repro.analysis on a canonical fixture):
@@ -505,15 +506,21 @@ def answer_batch(pool: SketchPool, queries: Sequence[Query], *,
             "(InfluenceService.admit does this automatically)")
     if alpha is None:
         alpha = 1.0 - 1.0 / math.e
-    k_max, excl, ks, budget_cov = _query_arrays(queries, pool.n,
-                                                pool.theta)
-    sol = maxcover.greedy_maxcover_batch(pool.r1, jnp.asarray(excl),
-                                         k_max, solver=solver)
-    seeds_t, _, cov1, cov2, k_used = _finalize_batch(
-        sol.seeds, sol.rows, sol.gains, jnp.asarray(ks),
-        jnp.asarray(budget_cov), pool.r2)
-    return _answers(pool, queries, seeds_t, cov1, cov2, k_used,
-                    delta=delta, alpha=alpha)
+    with span("service.prepare"):
+        k_max, excl, ks, budget_cov = _query_arrays(queries, pool.n,
+                                                    pool.theta)
+        excl, ks, budget_cov = (jnp.asarray(a)
+                                for a in (excl, ks, budget_cov))
+    with span("service.dispatch"):
+        sol = maxcover.greedy_maxcover_batch(pool.r1, excl, k_max,
+                                             solver=solver)
+        out = _finalize_batch(sol.seeds, sol.rows, sol.gains, ks,
+                              budget_cov, pool.r2)
+    with span("service.wait"):
+        seeds_t, _, cov1, cov2, k_used = jax.block_until_ready(out)
+    with span("service.answers"):
+        return _answers(pool, queries, seeds_t, cov1, cov2, k_used,
+                        delta=delta, alpha=alpha)
 
 
 def answer_one(pool: SketchPool, query: Query, *,

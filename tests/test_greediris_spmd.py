@@ -250,6 +250,37 @@ def test_sampler_triad_bit_identical_on_mesh():
     assert "single launch per step" in out
 
 
+def test_round_counters_sum_over_machines():
+    """The round's counters are psums over the 8 machines: the same on
+    every sampler, shuffle and aggregate; every set holds its root, and
+    every chunk of every machine takes a BFS step; a solver with no
+    counter of its own reports the full sweep (``RoundUnits``)."""
+    out = run_with_devices(_PRELUDE + textwrap.dedent("""
+        from repro.graphs.csr import padded_forward_adjacency
+        fwd = padded_forward_adjacency(g)
+        seen = set()
+        for shuffle in ("dense", "sparse"):
+            for sampler in ("dense", "packed"):
+                for aggregate in ("gather", "pipeline"):
+                    fn, _, theta = greediris.build_round(
+                        mesh, ("machines",), n=200, theta=512, k=8,
+                        max_degree=g.max_in_degree(), shuffle=shuffle,
+                        sampler=sampler, aggregate=aggregate,
+                        sample_chunks=2, solver="scan",
+                        fwd=(None if sampler == "dense" else fwd))
+                    o = jax.jit(fn)(nbr, prob, wt, key)
+                    u = fn.units
+                    assert int(o.sender_tiles_swept) == (
+                        u.sender_picks * u.sender_tiles_per_pick)
+                    seen.add((int(o.bfs_steps), int(o.rrr_pairs)))
+        (steps, pairs), = seen
+        assert steps >= 8 * 2 and pairs >= theta, (steps, pairs, theta)
+        assert u.sender_picks == 8 * 8
+        print("counters agree", steps, pairs)
+    """))
+    assert "counters agree" in out
+
+
 def test_gather_receiver_issues_one_stream_call(monkeypatch):
     """Acceptance criterion: under the gather schedule with use_kernel,
     the whole m*kk candidate stream goes through exactly ONE

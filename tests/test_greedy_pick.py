@@ -174,6 +174,28 @@ def test_lazy_swept_counter_exact_on_uniform_single_tile():
     assert int(swept) == 5
 
 
+@pytest.mark.parametrize("solver", SOLVERS)
+def test_cover_solution_carries_tiles_swept(solver):
+    """``CoverSolution.tiles_swept`` is the lazy kernel's own count of
+    the tiles it swept, and the full sweep k * tiles on every other
+    solver; one per query in a batch."""
+    from repro.kernels import lazy_greedy, ops
+
+    rng = np.random.default_rng(5)
+    n, w, k = 1000, 4, 6
+    density = 0.6 * (np.arange(n) + 1.0) ** -0.8
+    rows = bitset.pack_bool_matrix(
+        jnp.asarray(rng.random((n, w * 32)) < density[:, None]))
+    want = (int(ops.greedy_maxcover_lazy(rows, k)[4]) if solver == "lazy"
+            else k * lazy_greedy.num_row_tiles(n))
+    assert maxcover.full_sweep_tiles(n, k) == k * lazy_greedy.num_row_tiles(n)
+    assert int(maxcover.greedy_maxcover(rows, k, solver=solver)
+               .tiles_swept) == want
+    batch = maxcover.greedy_maxcover_batch(
+        rows, jnp.full((2, 1), -1, jnp.int32), k, solver=solver)
+    assert np.asarray(batch.tiles_swept).tolist() == [want, want]
+
+
 def test_use_kernel_alias_deprecated():
     """use_kernel still works (True -> fused, False -> scan) but warns."""
     rows = _random_rows(32, 2, seed=1)
