@@ -83,11 +83,14 @@ class GreediRISOut(NamedTuple):
     coverage: jnp.ndarray       # int32 [] coverage of returned seeds
     global_coverage: jnp.ndarray   # best streaming-receiver coverage
     best_local_coverage: jnp.ndarray
-    # Counters of the round's work, int32 [], summed over sample chunks
-    # and machines (denominators: ``RoundUnits``):
+    # Counters of the round's work, int32 [] but coins_drawn float32 [],
+    # summed over sample chunks and machines (denominators:
+    # ``RoundUnits``):
     bfs_steps: jnp.ndarray      # S1 BFS steps taken
     rrr_pairs: jnp.ndarray      # S1 (sample, vertex) pairs sampled
     sender_tiles_swept: jnp.ndarray   # S3 row tiles the picks swept
+    frontier_steps: jnp.ndarray  # S1 steps that drew for the frontier
+    coins_drawn: jnp.ndarray    # S1 uniforms drawn (float32)
 
 
 class RoundUnits(NamedTuple):
@@ -242,8 +245,8 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
     # it keeps kernelizing the S4 receiver either way.
     solver = maxcover.resolve_solver(solver, use_kernel or None)
     from repro.core.randgreedi import _normalize_survivors
-    from repro.core.rrr import (_rrr_batch_dense, _rrr_batch_packed,
-                                resolve_sampler)
+    from repro.core.rrr import (S1Counts, _rrr_batch_dense,
+                                _rrr_batch_packed, resolve_sampler)
     from repro.kernels import vmem_budget
     if gather not in vmem_budget.GATHER_MODES:
         # validate eagerly (the knob only binds inside the jitted
@@ -283,15 +286,15 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                         sample_chunks=sample_chunks, coin_chunk=coin_chunk)
 
     def sample_dense(nbr, prob, wt, roots, kb):
-        """One S1 batch under the dense sampler: (bool [b, n], steps)."""
+        """One S1 batch under the dense sampler: (bool [b, n], counts)."""
         return _rrr_batch_dense(nbr, prob, wt, roots, kb, model=model,
                                 max_steps=max_steps, coin_chunk=coin_chunk)
 
     def sample_packed(nbr, prob, wt, roots, kb):
-        """One S1 batch as (packed words [n, b/32], steps)."""
+        """One S1 batch as (packed words [n, b/32], counts)."""
         if sampler == "dense":
-            vis, steps = sample_dense(nbr, prob, wt, roots, kb)
-            return bitset.pack_bool_matrix(vis.T), steps   # [n, b/32]
+            vis, counts = sample_dense(nbr, prob, wt, roots, kb)
+            return bitset.pack_bool_matrix(vis.T), counts  # [n, b/32]
         return _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot,
                                  roots, kb, model=model,
                                  max_steps=max_steps,
@@ -309,23 +312,25 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
         perm = jax.random.permutation(
             jax.random.fold_in(key, 0x9E37), n_pad)
         inv_perm = jnp.argsort(perm)   # position of vertex v in perm
+        no_counts = S1Counts(jnp.int32(0), jnp.int32(0), jnp.float32(0))
 
         if shuffle == "dense":
             # --- S1: sample theta/m RRR sets, packed bitmatrix ---
             def one_chunk(i, state):
-                acc, steps = state
+                acc, counts = state
                 kc = jax.random.fold_in(key_p, i)
                 kr, kb = jax.random.split(kc)
                 b = theta_local // sample_chunks
                 roots = jax.random.randint(kr, (b,), 0, n)
                 packed, st = sample_packed(nbr, prob, wt, roots, kb)
                 return (lax.dynamic_update_slice(
-                    acc, packed, (0, i * (b // 32))), steps + st)
+                    acc, packed, (0, i * (b // 32))),
+                    jax.tree.map(jnp.add, counts, st))
 
             x_p = jnp.zeros((nbr.shape[0], w_local),
                             dtype=bitset.WORD_DTYPE)
-            x_p, bfs_steps = lax.fori_loop(0, sample_chunks, one_chunk,
-                                           (x_p, jnp.int32(0)))
+            x_p, s1 = lax.fori_loop(0, sample_chunks, one_chunk,
+                                    (x_p, no_counts))
             rrr_pairs = jnp.sum(bitset.popcount(x_p))
             if nbr.shape[0] < n_pad:
                 x_p = jnp.pad(x_p, ((0, n_pad - nbr.shape[0]), (0, 0)))
@@ -338,7 +343,7 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
             counts = jnp.zeros((m,), dtype=jnp.int32)
 
             def one_chunk(i, state):
-                send, counts, steps, rrr_pairs = state
+                send, counts, s1, rrr_pairs = state
                 kc = jax.random.fold_in(key_p, i)
                 kr, kb = jax.random.split(kc)
                 b = theta_local // sample_chunks
@@ -358,7 +363,7 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                         packed, size=size, fill_value=-1)
                     rrr_pairs = rrr_pairs + jnp.sum(
                         bitset.popcount(packed))
-                steps = steps + st
+                s1 = jax.tree.map(jnp.add, s1, st)
                 valid = s_idx >= 0
                 sample_gid = pid * theta_local + i * b + s_idx
                 pos = inv_perm[jnp.clip(v_idx, 0)]
@@ -375,13 +380,13 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                 send = send.at[d_c, s_c, 1].set(sample_gid, mode="drop")
                 counts = counts + jnp.sum(
                     onehot * ok[:, None].astype(jnp.int32), axis=0)
-                return send, counts, steps, rrr_pairs
+                return send, counts, s1, rrr_pairs
 
             # mark empty slots with sample id -1
             send = send.at[:, :, 1].set(-1)
-            send, counts, bfs_steps, rrr_pairs = lax.fori_loop(
+            send, counts, s1, rrr_pairs = lax.fori_loop(
                 0, sample_chunks, one_chunk,
-                (send, counts, jnp.int32(0), jnp.int32(0)))
+                (send, counts, no_counts, jnp.int32(0)))
             recv = lax.all_to_all(send, axes, split_axis=0,
                                   concat_axis=0, tiled=True)
             # rebuild packed rows [per, W_global]; each (v, s) pair is
@@ -499,9 +504,11 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
         seeds = jnp.where(take_global, g_seeds_best, lids_all[l_best])
         cov = jnp.maximum(g_cov_best, lc_all[l_best])
         return GreediRISOut(seeds, cov, g_cov_best, lc_all[l_best],
-                            lax.psum(bfs_steps, axes),
+                            lax.psum(s1.bfs_steps, axes),
                             lax.psum(rrr_pairs, axes),
-                            lax.psum(sol.tiles_swept, axes))
+                            lax.psum(sol.tiles_swept, axes),
+                            lax.psum(s1.frontier_steps, axes),
+                            lax.psum(s1.coins_drawn, axes))
 
     specs_in = (P(), P(), P(), P())  # graph + key replicated
     specs_out = GreediRISOut(*[P()] * len(GreediRISOut._fields))

@@ -43,37 +43,54 @@ Each expansion re-draws edge coins; under IC an edge is examined
 exactly once (its source is in the frontier exactly once), so per-step
 redraws are distributionally identical to a live-edge graph.
 
+Under IC the packed samplers draw coins for the frontier, not for the
+graph.  Every coin is a pure function of its key and its flat index
+in the dense path's ``[batch, n, chunk]`` draw (JAX's partitionable
+Threefry), so a BFS step whose frontier holds at most a fixed
+capacity of (sample, vertex) pairs (``_frontier_capacity``: twice the
+batch, in whole update blocks) computes only the coins of those pairs'
+in-edge slots, ORs the fired ``(sample, in-neighbor)`` bits into the
+state after sorting out duplicates, and never builds the step's coin
+plane.  A step with a larger frontier (supercritical IC) takes the
+dense step below through ``lax.cond``; both draw the same coins, so
+the branch never changes a result.  ``sampler="kernel"`` uses its
+kernel only in that fallback.
+
 LT uses the live-edge equivalence of Kempe et al.: every vertex selects
 at most one incoming edge (with probability = its weight); the RRR set
 is the chain of selected in-neighbors — this is why LT traversals are
 shallower, matching the paper's observation (§4.2).  The packed LT
 expansion reuses the IC machinery with the coin mask replaced by the
 packed one-hot edge-selection mask, so both models share one gather
-engine (and one Pallas kernel).
+engine (and one Pallas kernel).  LT steps are always dense.
 
-``coin_chunk`` bounds the IC coin draw (and the LT selection-mask
+``coin_chunk`` bounds the dense IC coin draw (and the LT selection-mask
 pack) to ``[batch, n, coin_chunk]`` slots at a time, so the bool coin
 intermediate is O(batch * n * coin_chunk) — not O(batch * n * d_max)
 — on every sampler; essential for skewed-degree graphs.  The packed
-samplers additionally accumulate the word-packed
+samplers' dense step additionally accumulates the word-packed
 ``[n, d_max, batch/32]`` per-step slot mask (each chunk packs over
 the batch lane immediately, so the mask costs batch/8 bytes per edge
 slot — 1/8 of an unchunked bool mask — but its d_max axis is *not*
 bounded by coin_chunk; on extreme-degree graphs the dense sampler is
-currently the lower-peak-memory choice).  The chunk width is part of
-the PRNG stream under IC (coins fold in the chunk index), so it acts
-like a seed: dense/packed/kernel parity holds at any fixed value, but
-changing it changes the sampled sets.
+currently the lower-peak-memory choice).  That fallback stays
+compiled in, so it sets the packed samplers' peak memory even where
+every step takes the frontier path, whose own buffers are
+O(capacity * d_max) plus the packed state.  The chunk width is part
+of the PRNG stream under IC (coins fold in the chunk index), so it
+acts like a seed: dense/packed/kernel parity holds at any fixed
+value, but changing it changes the sampled sets.
 """
 from __future__ import annotations
 
 import functools
-from typing import Literal, Optional, Tuple
+from typing import Literal, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.extend.random import threefry2x32_p
 
 from repro.core import bitset
 from repro.graphs.csr import (CSRGraph, padded_adjacency,
@@ -125,6 +142,24 @@ def coins_per_step(n: int, d: int, batch: int, *, model: str,
     return batch * n
 
 
+class S1Counts(NamedTuple):
+    """What one sampler call did."""
+    bfs_steps: jnp.ndarray       # int32: BFS steps taken
+    frontier_steps: jnp.ndarray  # int32: steps that drew for the frontier
+    # float32: uniforms drawn (one dense step can pass int32's range);
+    # a frontier step counts d_pad per frontier pair, not the padding
+    # of its fixed-capacity buffers
+    coins_drawn: jnp.ndarray
+
+
+def _dense_counts(steps, n: int, d: int, batch: int, *, model: str,
+                  coin_chunk: int) -> S1Counts:
+    """The counts of ``steps`` dense steps."""
+    plane = coins_per_step(n, d, batch, model=model, coin_chunk=coin_chunk)
+    return S1Counts(steps, jnp.int32(0),
+                    steps.astype(jnp.float32) * np.float32(plane))
+
+
 @functools.partial(
     jax.jit, static_argnames=("model", "max_steps", "sampler", "coin_chunk",
                               "gather", "block_v"))
@@ -162,13 +197,14 @@ def rrr_batch(nbr, prob, wt, roots, key, *, model: str, max_steps: int = 64,
 
 def _rrr_batch_dense(nbr, prob, wt, roots, key, *, model: str,
                      max_steps: int, coin_chunk: int):
-    """The dense BFS engine: (visited bool [batch, n], BFS steps)."""
+    """The dense BFS engine: (visited bool [batch, n], S1Counts)."""
     n, d = nbr.shape
     batch = roots.shape[0]
     visited0 = jnp.zeros((batch, n), dtype=bool).at[
         jnp.arange(batch), roots].set(True)
     if d == 0:          # edgeless graph: RRR(root) = {root}
-        return visited0, jnp.int32(0)
+        return visited0, _dense_counts(jnp.int32(0), n, d, batch,
+                                       model=model, coin_chunk=coin_chunk)
 
     valid = nbr >= 0
 
@@ -234,7 +270,8 @@ def _rrr_batch_dense(nbr, prob, wt, roots, key, *, model: str,
 
     _, visited, _, steps = jax.lax.while_loop(
         cond, body, (visited0, visited0, key, jnp.int32(0)))
-    return visited, steps
+    return visited, _dense_counts(steps, n, d, batch, model=model,
+                                  coin_chunk=coin_chunk)
 
 
 def _packed_roots(roots, n: int):
@@ -308,19 +345,179 @@ def _expand_packed(frontier, visited, fwd_nbr, fwd_rslot, mask,
     return new, visited | new
 
 
+# Frontier steps apply their updates this many at a time: a scatter on
+# the chip costs about the same for each update it is given, dropped
+# ones included, so blocks keep it to the updates a step has.
+_SCATTER_BLOCK = 1024
+
+
+def _frontier_capacity(batch: int) -> Tuple[int, int]:
+    """(pairs a frontier step holds, its update block): twice the batch,
+    rounded up to whole blocks.  A step with more pairs takes the dense
+    step."""
+    block = min(_SCATTER_BLOCK, 2 * batch)
+    return -(-2 * batch // block) * block, block
+
+
+def _wide_index(b, stride: int, off):
+    """(hi, lo) uint32 words of ``b * stride + off`` for uint32 ``b``
+    and ``off`` and a static ``stride`` below 2**32, exact past 2**32
+    without 64-bit types: the product is taken in 16-bit limbs."""
+    b = b.astype(jnp.uint32)
+    b0, b1 = b & 0xFFFF, b >> 16
+    s0, s1 = np.uint32(stride & 0xFFFF), np.uint32(stride >> 16)
+    p00, p01, p10 = b0 * s0, b0 * s1, b1 * s0
+    mid = (p00 >> 16) + (p01 & 0xFFFF) + (p10 & 0xFFFF)
+    lo = (p00 & 0xFFFF) | (mid << 16)
+    hi = b1 * s1 + (p01 >> 16) + (p10 >> 16) + (mid >> 16)
+    lo_off = lo + off.astype(jnp.uint32)
+    return hi + (lo_off < lo).astype(jnp.uint32), lo_off
+
+
+def _threefry_words(key):
+    """The two uint32 words of a Threefry key, or None for a key of
+    another generator."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        if str(jax.random.key_impl(key)) != "threefry2x32":
+            return None
+        return jax.random.key_data(key)
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return None
+    return key
+
+
+def _uniform_at(key, hi, lo):
+    """``jax.random.uniform(key, shape)`` (float32 on [0, 1)) at the
+    flat indices ``(hi, lo)`` of ``shape``, under JAX's partitionable
+    Threefry: bits ``y1 ^ y2`` of ``threefry2x32(key, (hi, lo))``,
+    converted as ``uniform`` converts them."""
+    kw = _threefry_words(key)
+    y1, y2 = threefry2x32_p.bind(jnp.broadcast_to(kw[0], hi.shape),
+                                 jnp.broadcast_to(kw[1], hi.shape), hi, lo)
+    bits = ((y1 ^ y2) >> 9) | np.uint32(0x3F800000)
+    return lax.bitcast_convert_type(bits, jnp.float32) - np.float32(1.0)
+
+
+def _first_above(cum, x):
+    """For each ``x[i]``, the first index of the nondecreasing ``cum``
+    whose value passes it (``searchsorted(cum, x, side="right")``), by
+    comparisons against the ends of 128-entry blocks and then within
+    one block: no sequential search."""
+    blk = 128
+    nb = -(-cum.shape[0] // blk)
+    cum = jnp.pad(cum, (0, nb * blk - cum.shape[0]),
+                  constant_values=cum[-1]).reshape(nb, blk)
+    vb = jnp.minimum(jnp.sum(cum[None, :, -1] <= x[:, None], axis=1),
+                     nb - 1)
+    return vb * blk + jnp.sum(cum[vb] <= x[:, None], axis=1)
+
+
+def _frontier_pairs(frontier, row_pairs, cap: int):
+    """The frontier's (sample, vertex) pairs, at most ``cap`` of them
+    (callers ensure it): int32 ``(b, v, on)`` of length ``cap``, pairs
+    in vertex order, ``on`` false past the last pair.  Pair i lies in
+    the first row whose running pair count passes i, then in the word
+    and the bit of that row where the count does."""
+    n, w = frontier.shape
+    cum = jnp.cumsum(row_pairs)
+    i = jnp.arange(cap, dtype=jnp.int32)
+    v = jnp.minimum(_first_above(cum, i), n - 1)
+    rank = i - (cum[v] - row_pairs[v])
+    row = frontier[v]                                   # [cap, W]
+    wpc = bitset.popcount(row)
+    wcum = jnp.cumsum(wpc, axis=1)
+    wi = jnp.minimum(jnp.sum(wcum <= rank[:, None], axis=1), w - 1)
+    at = wi[:, None]
+    rank = rank - (jnp.take_along_axis(wcum - wpc, at, axis=1)[:, 0])
+    word = jnp.take_along_axis(row, at, axis=1)         # [cap, 1]
+    bits = (word >> jnp.arange(bitset.WORD_BITS, dtype=jnp.uint32)) & 1
+    bcum = jnp.cumsum(bits.astype(jnp.int32), axis=1)
+    j = jnp.sum(bcum <= rank[:, None], axis=1)
+    return (wi * bitset.WORD_BITS + jnp.minimum(j, bitset.WORD_BITS - 1),
+            v, i < cum[-1])
+
+
+def _frontier_step(frontier, visited, sub, row_pairs, *, nbr_p, prob_p,
+                   chunk: int, n_chunks: int, cap: int, block: int):
+    """One IC step drawing coins for the frontier's pairs only.
+
+    Pair ``(b, v)`` draws, for each slot ``s`` of ``v``'s reverse row,
+    the coin that the dense step draws at ``(b, v, s % chunk)`` of its
+    ``uniform(fold_in(sub, s // chunk), (batch, n, chunk))``.  The
+    fired ``(nbr[v, s], b)`` are sorted, so that they come first and
+    duplicates (two frontier vertices of one sample reaching one
+    in-neighbor) sit side by side and drop out; so do already visited
+    vertices, and the rest are distinct single bits, which scatter-add
+    ORs exactly.  ``new`` is the frontier's array with its pairs'
+    words cleared in place.  Clears and hits are applied ``block`` at a
+    time, as many blocks as there are pairs and fired slots.  Returns
+    (new, visited | new, any new, coins drawn)."""
+    n, w = frontier.shape
+    b, v, on = _frontier_pairs(frontier, row_pairs, cap)
+    off = (v[:, None].astype(jnp.uint32) * np.uint32(chunk)
+           + jnp.arange(chunk, dtype=jnp.uint32)[None])
+    hi, lo = _wide_index(jnp.broadcast_to(b[:, None], off.shape),
+                         n * chunk, off)                # [cap, chunk]
+    coins = jnp.concatenate(
+        [_uniform_at(jax.random.fold_in(sub, c), hi, lo)
+         for c in range(n_chunks)], axis=1)             # [cap, d_pad]
+    tgt = nbr_p[v]
+    fire = on[:, None] & (tgt >= 0) & (coins < prob_p[v])
+    tu, tb = lax.sort((jnp.where(fire, tgt, n).reshape(-1),
+                       jnp.broadcast_to(b[:, None], fire.shape).reshape(-1)),
+                      num_keys=2)
+    first = jnp.ones(tu.shape, bool).at[1:].set(
+        (tu[1:] != tu[:-1]) | (tb[1:] != tb[:-1]))
+
+    def blocks(count):
+        return (count + block - 1) // block
+
+    def part(k, *arrays):
+        return (lax.dynamic_slice(a, (k * block,), (block,)) for a in arrays)
+
+    def clear(k, new):
+        cv, cb, c_on = part(k, v, b, on)
+        return new.at[jnp.where(c_on, cv, n), cb // bitset.WORD_BITS].set(
+            jnp.uint32(0), mode="drop")
+
+    def hit(k, state):
+        new, visited, grew = state
+        u, sb, f = part(k, tu, tb, first)
+        word = sb // bitset.WORD_BITS
+        bit = jnp.uint32(1) << (sb % bitset.WORD_BITS).astype(jnp.uint32)
+        seen = (visited[jnp.minimum(u, n - 1), word] & bit) != 0
+        keep = (u < n) & f & ~seen
+        row = jnp.where(keep, u, n)                     # n: dropped
+        add = jnp.where(keep, bit, jnp.uint32(0))
+        return (new.at[row, word].add(add, mode="drop"),
+                visited.at[row, word].add(add, mode="drop"),
+                grew | jnp.any(keep))
+
+    pairs = jnp.sum(on, dtype=jnp.int32)
+    new = lax.fori_loop(0, blocks(pairs), clear, frontier)   # all zero
+    new, visited, grew = lax.fori_loop(
+        0, blocks(jnp.sum(fire, dtype=jnp.int32)), hit,
+        (new, visited, jnp.bool_(False)))
+    drawn = pairs.astype(jnp.float32) * np.float32(coins.shape[1])
+    return new, visited, grew, drawn
+
+
 def _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
                       model: str, max_steps: int, coin_chunk: int,
                       kernel: bool, gather: str = "auto",
                       block_v: Optional[int] = None):
     """The packed BFS engine shared by sampler="packed" and "kernel":
-    (visited uint32 [n, W], BFS steps taken)."""
+    (visited uint32 [n, W], S1Counts)."""
     n, d = nbr.shape
     batch = roots.shape[0]
     visited0 = _packed_roots(roots, n)
     if d == 0:          # edgeless graph: RRR(root) = {root}
-        return visited0, jnp.int32(0)
+        return visited0, _dense_counts(jnp.int32(0), n, d, batch,
+                                       model=model, coin_chunk=coin_chunk)
     valid = nbr >= 0
     chunk, n_chunks, d_pad = _coin_chunks(d, coin_chunk)
+    plane = np.float32(coins_per_step(n, d, batch, model=model,
+                                      coin_chunk=coin_chunk))
 
     if model == "IC":
         prob_p = (jnp.pad(prob, ((0, 0), (0, d_pad - d)))
@@ -365,21 +562,54 @@ def _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
                               dtype=bitset.WORD_DTYPE)
             return lax.fori_loop(0, n_chunks, one, mask0)
 
-    def body(state):
-        frontier, visited, k, step = state
-        k, sub = jax.random.split(k)
+    def dense_step(frontier, visited, sub, _):
         new, visited = _expand_packed(frontier, visited, fwd_nbr,
                                       fwd_rslot, step_mask(sub), kernel,
                                       gather=gather, block_v=block_v)
-        return new, visited, k, step + 1
+        return new, visited, jnp.any(new), plane
+
+    # The frontier step needs each coin as a function of its index:
+    # IC under a partitionable Threefry key, flat indices within uint32
+    # words.
+    cap, block = _frontier_capacity(batch)
+    if (model == "IC" and _threefry_words(key) is not None
+            and jax.config.jax_threefry_partitionable
+            and n * chunk < 2 ** 32):
+        nbr_p = (jnp.pad(nbr, ((0, 0), (0, d_pad - d)), constant_values=-1)
+                 if d_pad != d else nbr)
+        sparse_step = functools.partial(
+            _frontier_step, nbr_p=nbr_p, prob_p=prob_p, chunk=chunk,
+            n_chunks=n_chunks, cap=cap, block=block)
+
+        def step(frontier, visited, sub):
+            row_pairs = jnp.sum(bitset.popcount(frontier), axis=1)
+            # float32 sums are exact up to 2**24 > cap and never fall
+            # back below cap once past it, where int32 could wrap
+            fits = jnp.sum(row_pairs, dtype=jnp.float32) <= cap
+            out = lax.cond(fits, sparse_step, dense_step, frontier,
+                           visited, sub, row_pairs)
+            return out + (fits,)
+    else:
+        def step(frontier, visited, sub):
+            return dense_step(frontier, visited, sub, None) + (
+                jnp.bool_(False),)
+
+    def body(state):
+        frontier, visited, k, live, counts = state
+        k, sub = jax.random.split(k)
+        new, visited, live, drawn, sparse = step(frontier, visited, sub)
+        counts = S1Counts(counts.bfs_steps + 1,
+                          counts.frontier_steps + sparse.astype(jnp.int32),
+                          counts.coins_drawn + drawn)
+        return new, visited, k, live, counts
 
     def cond(state):
-        frontier, _, _, step = state
-        return jnp.any(frontier) & (step < max_steps)
+        return state[3] & (state[4].bfs_steps < max_steps)
 
-    _, visited, _, steps = jax.lax.while_loop(
-        cond, body, (visited0, visited0, key, jnp.int32(0)))
-    return visited, steps
+    zero = S1Counts(jnp.int32(0), jnp.int32(0), jnp.float32(0))
+    _, visited, _, _, counts = jax.lax.while_loop(
+        cond, body, (visited0, visited0, key, jnp.any(visited0), zero))
+    return visited, counts
 
 
 @functools.partial(

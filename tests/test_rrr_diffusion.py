@@ -1,3 +1,5 @@
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -279,3 +281,146 @@ def test_edgeless_graph_rrr_is_root_only():
             np.testing.assert_array_equal(
                 np.asarray(bitset.pack_bool_matrix(dense.T)),
                 np.asarray(packed))
+
+
+# ---------------------------------------------------------------------
+# Frontier coin draw: IC steps with a small frontier draw coins for its
+# pairs only, bit-identical to the dense draw
+# ---------------------------------------------------------------------
+from repro.core import rrr as rrr_mod
+
+
+def _p1_graph(src, dst, n):
+    return from_edge_list(np.asarray(src), np.asarray(dst), n,
+                          probs=np.ones(len(src), dtype=np.float32))
+
+
+def _deep_graph():
+    """Deep IC sets: G(n, m) with p = 0.2, so frontiers of several
+    vertices per sample meet in-neighbors they share."""
+    g = generators.erdos_renyi(60, 6.0, seed=9)
+    dst = np.repeat(np.arange(60), np.diff(np.asarray(g.indptr)))
+    return from_edge_list(np.asarray(g.indices), dst, 60,
+                          probs=np.full(len(dst), 0.2, np.float32))
+
+
+def _frontier_cases():
+    n = 20                          # complete graph, p = 1: the second
+    src, dst = np.nonzero(~np.eye(n, dtype=bool))    # step overflows
+    return {
+        "parity_graphs": [(g, 64, None) for g in _parity_graphs()],
+        "dense_fallback": [(_p1_graph(src, dst, n), 64, None)],
+        # 3 -> 1 -> 0 and 3 -> 2 -> 0: both frontier vertices of each
+        # sample reach 3 in the same step
+        "duplicate_hits": [(_p1_graph([3, 3, 1, 2], [1, 2, 0, 0], 4), 40,
+                            np.zeros(40, np.int32))],
+        "unaligned_batch": [(_deep_graph(), b, None) for b in (40, 72)],
+        # 1024 roots at 0, each reaching 1 and 2, which reach 3 at p =
+        # 0.5: the first step's 2048 hits and the second step's 2048
+        # pairs take two update blocks each
+        "update_blocks": [(from_edge_list(
+            np.array([1, 2, 3, 3]), np.array([0, 0, 1, 2]), 4,
+            probs=np.array([1, 1, 0.5, 0.5], np.float32)), 1024,
+            np.zeros(1024, np.int32))],
+    }
+
+
+@pytest.mark.parametrize("kernel", (False, True))
+@pytest.mark.parametrize("case", ("parity_graphs", "dense_fallback",
+                                  "duplicate_hits", "unaligned_batch",
+                                  "update_blocks"))
+def test_frontier_steps_bit_identical_to_dense(case, kernel):
+    """The packed engine's frontier steps (and, where the frontier
+    outgrows their capacity, its dense fallback) give the dense
+    sampler's incidence bit for bit; the counters say which ran."""
+    for g, batch, roots in _frontier_cases()[case]:
+        n = g.num_vertices
+        nbr, prob, wt = padded_adjacency(g)
+        fwd = padded_forward_adjacency(g)
+        if roots is None:
+            roots = jax.random.randint(jax.random.key(7), (batch,), 0, n)
+        key = jax.random.key(5)
+        dense, dcount = rrr_mod._rrr_batch_dense(
+            nbr, prob, wt, roots, key, model="IC", max_steps=32,
+            coin_chunk=32)
+        packed, count = jax.jit(functools.partial(
+            rrr_mod._rrr_batch_packed, model="IC", max_steps=32,
+            coin_chunk=32, kernel=kernel))(nbr, prob, wt, *fwd, roots, key)
+        np.testing.assert_array_equal(
+            np.asarray(bitset.pack_bool_matrix(dense.T)),
+            np.asarray(packed))
+        steps, front = int(count.bfs_steps), int(count.frontier_steps)
+        assert steps == int(dcount.bfs_steps) and int(
+            dcount.frontier_steps) == 0
+        d_pad = nbr.shape[1]
+        plane = batch * n * d_pad
+        if case == "dense_fallback":
+            assert 0 < front < steps
+            assert float(count.coins_drawn) > plane
+        else:
+            # every step fits: each sampled pair drew its d_pad coins
+            # once, as the frontier that it joined was expanded
+            assert front == steps
+            assert float(count.coins_drawn) == d_pad * int(
+                np.sum(np.asarray(bitset.popcount(packed))))
+        if case == "duplicate_hits":
+            assert steps == 3 and np.asarray(dense).all()
+        if case == "update_blocks":
+            assert steps == 3 and np.asarray(dense)[:, :3].all()
+            assert 0 < np.asarray(dense)[:, 3].sum() < batch
+
+
+def test_wide_index_matches_python_ints():
+    """(hi, lo) of b * stride + off agree with Python's integers past
+    2**32, at the round cell's sizes and at the extremes."""
+    n, c = 317080, 21
+    stride = n * c
+    rng = np.random.default_rng(0)
+    b = np.concatenate([rng.integers(0, 4096, 500), [0, 4095, 2**32 - 1]])
+    off = np.concatenate([rng.integers(0, stride, 500),
+                          [stride - 1, 0, 2**32 - 1]])
+    for s in (stride, 2**32 - 1, 1):
+        hi, lo = rrr_mod._wide_index(jnp.asarray(b, jnp.uint32), s,
+                                     jnp.asarray(off, jnp.uint32))
+        want = [int(x) * s + int(y) for x, y in zip(b, off)]
+        got = [(int(h) << 32) | int(lv) for h, lv in
+               zip(np.asarray(hi), np.asarray(lo))]
+        assert got == want
+        assert max(want) >= 2**32
+    # the cell's largest index: 4096 x 317080 x 21 coins in one draw
+    hi, lo = rrr_mod._wide_index(jnp.uint32(4095), stride,
+                                 jnp.uint32(stride - 1))
+    assert (int(hi) << 32) | int(lo) == 4096 * stride - 1 > 2**32
+
+
+@pytest.mark.parametrize("typed", (True, False))
+def test_uniform_at_matches_jax_uniform(typed):
+    """The helper's uniforms equal ``jax.random.uniform`` element by
+    element under ``fold_in(sub, c)`` for c > 0, for typed and raw
+    Threefry keys."""
+    k = jax.random.key(3) if typed else jax.random.PRNGKey(3)
+    _, sub = jax.random.split(k)
+    shape = (6, 7, 5)
+    for c in (1, 4):
+        kc = jax.random.fold_in(sub, c)
+        want = np.asarray(jax.random.uniform(kc, shape))
+        b, v, j = np.meshgrid(*map(np.arange, shape), indexing="ij")
+        hi, lo = rrr_mod._wide_index(
+            jnp.asarray(b, jnp.uint32), shape[1] * shape[2],
+            jnp.asarray(v * shape[2] + j, jnp.uint32))
+        got = np.asarray(rrr_mod._uniform_at(kc, hi, lo))
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("length", (1, 128, 300, 1000))
+def test_first_above_matches_searchsorted(length):
+    """The blocked search over a running pair count gives
+    ``searchsorted(side="right")``, across block ends, on repeated
+    values and past the last entry."""
+    rng = np.random.default_rng(length)
+    cum = np.cumsum(rng.integers(0, 3, length)).astype(np.int32)
+    x = np.arange(int(cum[-1]) + 3, dtype=np.int32)
+    got = rrr_mod._first_above(jnp.asarray(cum), jnp.asarray(x))
+    np.testing.assert_array_equal(
+        np.minimum(np.asarray(got), length),
+        np.searchsorted(cum, x, side="right"))
