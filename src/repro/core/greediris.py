@@ -91,13 +91,14 @@ class GreediRISOut(NamedTuple):
     sender_tiles_swept: jnp.ndarray   # S3 row tiles the picks swept
     frontier_steps: jnp.ndarray  # S1 steps that drew for the frontier
     coins_drawn: jnp.ndarray    # S1 uniforms drawn (float32)
+    frontier_pairs: jnp.ndarray  # S1 (sample, vertex) pairs those held
 
 
 class RoundUnits(NamedTuple):
     """Static sizes of a round's work, the denominators of its
     counters: ``build_round(...)[0].units``, or ``round_units`` with
     the same arguments."""
-    coins_per_bfs_step: int     # uniforms one S1 BFS step draws
+    coins_per_bfs_step: int     # uniforms one dense S1 BFS step draws
     sender_picks: int           # S3 picks, over all machines
     sender_tiles_per_pick: int  # row tiles one full S3 pick sweeps
 
@@ -127,6 +128,17 @@ def round_units(*, n: int, theta: int, k: int, max_degree: int,
         sender_tiles_per_pick=maxcover.full_sweep_tiles(per, 1))
 
 
+def frontier_capacity(*, n: int, theta: int, machines: int,
+                      model: str = "IC", sample_chunks: int = 1) -> int:
+    """The (sample, vertex) pairs one S1 frontier step of
+    ``build_round`` is sized for, the denominator of
+    ``GreediRISOut.frontier_pairs`` per frontier step; also
+    ``build_round(...)[0].frontier_capacity``."""
+    from repro.core.rrr import _frontier_capacity
+    _, _, theta_local = _round_sizes(n, theta, machines)
+    return _frontier_capacity(theta_local // sample_chunks, model)[0]
+
+
 def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                 max_degree: int, model: str = "IC", delta: float = 0.077,
                 alpha_trunc: float = 1.0, aggregate: str = "gather",
@@ -146,7 +158,9 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
     all machines").  Returns a function suitable for jax.jit with the
     given mesh, the padded vertex count and the rounded theta.  The
     function's ``units`` attribute (:class:`RoundUnits`) holds the
-    denominators of the counters its :class:`GreediRISOut` carries.
+    denominators of the counters its :class:`GreediRISOut` carries,
+    and ``frontier_capacity`` (:func:`frontier_capacity`) the pairs one
+    S1 frontier step is sized for.
 
     solver: S3 sender path — "scan" | "fused" | "resident" | "lazy"
     (see the module docstring; all bit-identical).  None defaults from the
@@ -179,8 +193,9 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
 
     coin_chunk: IC coin-draw slot width inside the sampler BFS.  It
     bounds the per-step *bool coin intermediate* to
-    O(batch * n * coin_chunk) on every sampler; the packed samplers
-    additionally hold the word-packed [n, d_max, batch/32] slot mask
+    O(batch * n * coin_chunk) on every sampler; under IC the packed
+    samplers additionally hold the word-packed [n, d_max, batch/32]
+    slot mask of their dense fallback step
     (batch/8 bytes per edge slot — 1/8 of an unchunked bool mask, but
     not bounded by coin_chunk; see ``repro.core.rrr``).  Under IC the
     chunk index is folded into the PRNG stream, so the knob acts like
@@ -312,7 +327,8 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
         perm = jax.random.permutation(
             jax.random.fold_in(key, 0x9E37), n_pad)
         inv_perm = jnp.argsort(perm)   # position of vertex v in perm
-        no_counts = S1Counts(jnp.int32(0), jnp.int32(0), jnp.float32(0))
+        no_counts = S1Counts(jnp.int32(0), jnp.int32(0), jnp.float32(0),
+                             jnp.int32(0))
 
         if shuffle == "dense":
             # --- S1: sample theta/m RRR sets, packed bitmatrix ---
@@ -508,13 +524,17 @@ def build_round(mesh, axes: Sequence[str], *, n: int, theta: int, k: int,
                             lax.psum(rrr_pairs, axes),
                             lax.psum(sol.tiles_swept, axes),
                             lax.psum(s1.frontier_steps, axes),
-                            lax.psum(s1.coins_drawn, axes))
+                            lax.psum(s1.coins_drawn, axes),
+                            lax.psum(s1.frontier_pairs, axes))
 
     specs_in = (P(), P(), P(), P())  # graph + key replicated
     specs_out = GreediRISOut(*[P()] * len(GreediRISOut._fields))
     fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=specs_in,
                        out_specs=specs_out, check_vma=False)
     fn.units = units
+    fn.frontier_capacity = frontier_capacity(
+        n=n, theta=theta, machines=m, model=model,
+        sample_chunks=sample_chunks)
     return fn, n_pad, theta_local * m
 
 

@@ -43,43 +43,49 @@ Each expansion re-draws edge coins; under IC an edge is examined
 exactly once (its source is in the frontier exactly once), so per-step
 redraws are distributionally identical to a live-edge graph.
 
-Under IC the packed samplers draw coins for the frontier, not for the
-graph.  Every coin is a pure function of its key and its flat index
-in the dense path's ``[batch, n, chunk]`` draw (JAX's partitionable
-Threefry), so a BFS step whose frontier holds at most a fixed
-capacity of (sample, vertex) pairs (``_frontier_capacity``: twice the
-batch, in whole update blocks) computes only the coins of those pairs'
-in-edge slots, ORs the fired ``(sample, in-neighbor)`` bits into the
-state after sorting out duplicates, and never builds the step's coin
-plane.  A step with a larger frontier (supercritical IC) takes the
-dense step below through ``lax.cond``; both draw the same coins, so
-the branch never changes a result.  ``sampler="kernel"`` uses its
-kernel only in that fallback.
+The packed samplers draw for the frontier, not for the graph.  Every
+uniform is a pure function of its key and its flat index in the dense
+path's draw (JAX's partitionable Threefry), so a BFS step whose
+frontier holds at most a fixed capacity of (sample, vertex) pairs
+(``_frontier_capacity``, in whole update blocks) computes only its
+pairs' uniforms and never builds the step's coin plane.  Under IC a
+pair draws the coins of its in-edge slots, and the fired ``(sample,
+in-neighbor)`` bits are ORed into the state after sorting out
+duplicates; the capacity is twice the batch, and a step with a larger
+frontier (supercritical IC) takes the dense step below through
+``lax.cond``.  Both draw the same coins, so the branch never changes a
+result.  ``sampler="kernel"`` uses its kernel only in that fallback.
 
 LT uses the live-edge equivalence of Kempe et al.: every vertex selects
 at most one incoming edge (with probability = its weight); the RRR set
-is the chain of selected in-neighbors — this is why LT traversals are
-shallower, matching the paper's observation (§4.2).  The packed LT
-expansion reuses the IC machinery with the coin mask replaced by the
-packed one-hot edge-selection mask, so both models share one gather
-engine (and one Pallas kernel).  LT steps are always dense.
+is the chain of selected in-neighbors, so each sample's frontier holds
+at most one vertex.  A step then holds at most ``batch`` pairs, its
+capacity: every LT step of the packed samplers is a frontier step, and
+each pair draws one uniform and follows the first in-edge whose running
+weight sum (``_running_sums``, added left to right) passes it.  Chains
+are thin but not short: on a G(n, m) graph of mean degree 6.6 a round
+of 4 x 4096 LT sets takes about 220 BFS steps and its sets hold 7.6
+vertices, against 33 steps and 1.49 vertices under IC with p ~ U[0,
+0.1].  Under a key whose uniforms cannot be computed by index, the LT
+step draws the dense path's whole ``[batch, n]`` plane and reads its
+pairs from it.
 
-``coin_chunk`` bounds the dense IC coin draw (and the LT selection-mask
-pack) to ``[batch, n, coin_chunk]`` slots at a time, so the bool coin
-intermediate is O(batch * n * coin_chunk) — not O(batch * n * d_max)
-— on every sampler; essential for skewed-degree graphs.  The packed
-samplers' dense step additionally accumulates the word-packed
-``[n, d_max, batch/32]`` per-step slot mask (each chunk packs over
-the batch lane immediately, so the mask costs batch/8 bytes per edge
-slot — 1/8 of an unchunked bool mask — but its d_max axis is *not*
-bounded by coin_chunk; on extreme-degree graphs the dense sampler is
-currently the lower-peak-memory choice).  That fallback stays
-compiled in, so it sets the packed samplers' peak memory even where
-every step takes the frontier path, whose own buffers are
-O(capacity * d_max) plus the packed state.  The chunk width is part
-of the PRNG stream under IC (coins fold in the chunk index), so it
-acts like a seed: dense/packed/kernel parity holds at any fixed
-value, but changing it changes the sampled sets.
+``coin_chunk`` bounds the dense IC coin draw to ``[batch, n,
+coin_chunk]`` slots at a time, so the bool coin intermediate is
+O(batch * n * coin_chunk) — not O(batch * n * d_max) — on every
+sampler; essential for skewed-degree graphs.  The packed samplers'
+dense IC step additionally accumulates the word-packed ``[n, d_max,
+batch/32]`` per-step slot mask (each chunk packs over the batch lane
+immediately, so the mask costs batch/8 bytes per edge slot — 1/8 of an
+unchunked bool mask — but its d_max axis is *not* bounded by
+coin_chunk; on extreme-degree graphs the dense sampler is currently
+the lower-peak-memory choice).  That fallback stays compiled in under
+IC, so it sets the packed samplers' peak memory even where every step
+takes the frontier path, whose own buffers are O(capacity * d_max)
+plus the packed state.  The chunk width is part of the PRNG stream
+under IC (coins fold in the chunk index), so it acts like a seed:
+dense/packed/kernel parity holds at any fixed value, but changing it
+changes the sampled sets.
 """
 from __future__ import annotations
 
@@ -131,10 +137,11 @@ def _coin_chunks(d: int, coin_chunk: int) -> Tuple[int, int, int]:
 
 def coins_per_step(n: int, d: int, batch: int, *, model: str,
                    coin_chunk: int) -> int:
-    """Uniforms every sampler draws in one BFS step of a batch, whatever
-    its frontier holds: under IC one per (sample, vertex, edge slot) of
-    the degree-chunked ``[batch, n, d_pad]`` coin plane, under LT one
-    per (sample, vertex)."""
+    """Uniforms one dense BFS step of a batch draws, whatever its
+    frontier holds (every step of the dense sampler, and a step of the
+    packed samplers that does not fit the frontier path): under IC one
+    per (sample, vertex, edge slot) of the degree-chunked ``[batch, n,
+    d_pad]`` coin plane, under LT one per (sample, vertex)."""
     if d == 0:
         return 0
     if model == "IC":
@@ -147,9 +154,10 @@ class S1Counts(NamedTuple):
     bfs_steps: jnp.ndarray       # int32: BFS steps taken
     frontier_steps: jnp.ndarray  # int32: steps that drew for the frontier
     # float32: uniforms drawn (one dense step can pass int32's range);
-    # a frontier step counts d_pad per frontier pair, not the padding
-    # of its fixed-capacity buffers
+    # a frontier step counts d_pad per frontier pair under IC and one
+    # under LT, not the padding of its fixed-capacity buffers
     coins_drawn: jnp.ndarray
+    frontier_pairs: jnp.ndarray  # int32: pairs the frontier steps held
 
 
 def _dense_counts(steps, n: int, d: int, batch: int, *, model: str,
@@ -157,7 +165,21 @@ def _dense_counts(steps, n: int, d: int, batch: int, *, model: str,
     """The counts of ``steps`` dense steps."""
     plane = coins_per_step(n, d, batch, model=model, coin_chunk=coin_chunk)
     return S1Counts(steps, jnp.int32(0),
-                    steps.astype(jnp.float32) * np.float32(plane))
+                    steps.astype(jnp.float32) * np.float32(plane),
+                    jnp.int32(0))
+
+
+def _running_sums(wt):
+    """Each row's running LT weight sums ``[n, d]``, added slot by slot
+    from the left in float32, as the reference adds them: a parallel
+    prefix sum may round differently, and a draw between the two sums
+    would then pick another in-edge."""
+    def add(acc, col):
+        acc = acc + col
+        return acc, acc
+
+    _, cols = lax.scan(add, jnp.zeros(wt.shape[:1], wt.dtype), wt.T)
+    return cols.T
 
 
 @functools.partial(
@@ -244,7 +266,7 @@ def _rrr_batch_dense(nbr, prob, wt, roots, key, *, model: str,
             return new, visited | new, k, step + 1
     else:  # LT live-edge: newly reached v follows exactly one in-edge,
         # edge j selected with prob wt[v, j] (possibly none).
-        cumw = jnp.cumsum(wt, axis=1)  # [n, d]
+        cumw = _running_sums(wt)  # [n, d]
 
         def body(state):
             frontier, visited, k, step = state
@@ -303,7 +325,7 @@ def _expand_packed(frontier, visited, fwd_nbr, fwd_rslot, mask,
     """One packed BFS expansion: gather over the forward adjacency.
 
     frontier/visited: uint32 [n, W] packed state.
-    mask: uint32 [n, d_pad, W] per-step packed coin/selection masks
+    mask: uint32 [n, d_pad, W] per-step packed IC coin masks
       (bit b of mask[v, slot] = "sample b's traversal crosses reverse
       edge slot ``slot`` of v this step").
     Returns (new, visited | new).
@@ -351,12 +373,14 @@ def _expand_packed(frontier, visited, fwd_nbr, fwd_rslot, mask,
 _SCATTER_BLOCK = 1024
 
 
-def _frontier_capacity(batch: int) -> Tuple[int, int]:
-    """(pairs a frontier step holds, its update block): twice the batch,
-    rounded up to whole blocks.  A step with more pairs takes the dense
-    step."""
-    block = min(_SCATTER_BLOCK, 2 * batch)
-    return -(-2 * batch // block) * block, block
+def _frontier_capacity(batch: int, model: str) -> Tuple[int, int]:
+    """(pairs a frontier step holds, its update block), rounded up to
+    whole blocks: twice the batch under IC, where a step with more
+    pairs takes the dense step; the batch under LT, whose frontier
+    holds at most one vertex a sample."""
+    most = batch if model == "LT" else 2 * batch
+    block = min(_SCATTER_BLOCK, most)
+    return -(-most // block) * block, block
 
 
 def _wide_index(b, stride: int, off):
@@ -437,6 +461,51 @@ def _frontier_pairs(frontier, row_pairs, cap: int):
             v, i < cum[-1])
 
 
+def _apply_updates(frontier, visited, pairs, pb, pv, hits, hu, hb, hok, *,
+                   block: int):
+    """A frontier step's state from its pairs and its hits.
+
+    ``new`` is the frontier's array with the words of its first
+    ``pairs`` pairs ``(pb, pv)`` cleared in place (the frontier is
+    all its pairs, so it ends all zero); then each of the first
+    ``hits`` hits ``(hb, hu)`` where ``hok``, if ``hu`` is not yet
+    visited by sample ``hb``, is added to ``new`` and ``visited``.
+    Callers give hits that are distinct (sample, vertex) bits, which
+    scatter-add ORs exactly.  Clears and hits are applied ``block`` at
+    a time, as many blocks as there are pairs and hits.  Returns (new,
+    visited | new, any new)."""
+    n = frontier.shape[0]
+
+    def blocks(count):
+        return (count + block - 1) // block
+
+    def part(k, *arrays):
+        return (lax.dynamic_slice(a, (k * block,), (block,)) for a in arrays)
+
+    def clear(k, new):
+        cv, cb = part(k, pv, pb)
+        on = k * block + jnp.arange(block) < pairs
+        return new.at[jnp.where(on, cv, n), cb // bitset.WORD_BITS].set(
+            jnp.uint32(0), mode="drop")
+
+    def hit(k, state):
+        new, visited, grew = state
+        u, sb, ok = part(k, hu, hb, hok)
+        word = sb // bitset.WORD_BITS
+        bit = jnp.uint32(1) << (sb % bitset.WORD_BITS).astype(jnp.uint32)
+        seen = (visited[jnp.minimum(u, n - 1), word] & bit) != 0
+        keep = ok & ~seen
+        row = jnp.where(keep, u, n)                     # n: dropped
+        add = jnp.where(keep, bit, jnp.uint32(0))
+        return (new.at[row, word].add(add, mode="drop"),
+                visited.at[row, word].add(add, mode="drop"),
+                grew | jnp.any(keep))
+
+    new = lax.fori_loop(0, blocks(pairs), clear, frontier)   # all zero
+    return lax.fori_loop(0, blocks(hits), hit,
+                         (new, visited, jnp.bool_(False)))
+
+
 def _frontier_step(frontier, visited, sub, row_pairs, *, nbr_p, prob_p,
                    chunk: int, n_chunks: int, cap: int, block: int):
     """One IC step drawing coins for the frontier's pairs only.
@@ -447,11 +516,8 @@ def _frontier_step(frontier, visited, sub, row_pairs, *, nbr_p, prob_p,
     fired ``(nbr[v, s], b)`` are sorted, so that they come first and
     duplicates (two frontier vertices of one sample reaching one
     in-neighbor) sit side by side and drop out; so do already visited
-    vertices, and the rest are distinct single bits, which scatter-add
-    ORs exactly.  ``new`` is the frontier's array with its pairs'
-    words cleared in place.  Clears and hits are applied ``block`` at a
-    time, as many blocks as there are pairs and fired slots.  Returns
-    (new, visited | new, any new, coins drawn)."""
+    vertices (``_apply_updates``).  Returns (new, visited | new, any
+    new, coins drawn, pairs)."""
     n, w = frontier.shape
     b, v, on = _frontier_pairs(frontier, row_pairs, cap)
     off = (v[:, None].astype(jnp.uint32) * np.uint32(chunk)
@@ -468,38 +534,34 @@ def _frontier_step(frontier, visited, sub, row_pairs, *, nbr_p, prob_p,
                       num_keys=2)
     first = jnp.ones(tu.shape, bool).at[1:].set(
         (tu[1:] != tu[:-1]) | (tb[1:] != tb[:-1]))
-
-    def blocks(count):
-        return (count + block - 1) // block
-
-    def part(k, *arrays):
-        return (lax.dynamic_slice(a, (k * block,), (block,)) for a in arrays)
-
-    def clear(k, new):
-        cv, cb, c_on = part(k, v, b, on)
-        return new.at[jnp.where(c_on, cv, n), cb // bitset.WORD_BITS].set(
-            jnp.uint32(0), mode="drop")
-
-    def hit(k, state):
-        new, visited, grew = state
-        u, sb, f = part(k, tu, tb, first)
-        word = sb // bitset.WORD_BITS
-        bit = jnp.uint32(1) << (sb % bitset.WORD_BITS).astype(jnp.uint32)
-        seen = (visited[jnp.minimum(u, n - 1), word] & bit) != 0
-        keep = (u < n) & f & ~seen
-        row = jnp.where(keep, u, n)                     # n: dropped
-        add = jnp.where(keep, bit, jnp.uint32(0))
-        return (new.at[row, word].add(add, mode="drop"),
-                visited.at[row, word].add(add, mode="drop"),
-                grew | jnp.any(keep))
-
     pairs = jnp.sum(on, dtype=jnp.int32)
-    new = lax.fori_loop(0, blocks(pairs), clear, frontier)   # all zero
-    new, visited, grew = lax.fori_loop(
-        0, blocks(jnp.sum(fire, dtype=jnp.int32)), hit,
-        (new, visited, jnp.bool_(False)))
+    new, visited, grew = _apply_updates(
+        frontier, visited, pairs, b, v, jnp.sum(fire, dtype=jnp.int32),
+        tu, tb, (tu < n) & first, block=block)
     drawn = pairs.astype(jnp.float32) * np.float32(coins.shape[1])
-    return new, visited, grew, drawn
+    return new, visited, grew, drawn, pairs
+
+
+def _lt_frontier_step(frontier, visited, sub, row_pairs, *, nbr, cumw,
+                      in_deg, uniform, cap: int, block: int):
+    """One LT step: each frontier pair ``(b, v)`` draws the dense step's
+    uniform at ``(b, v)`` of its ``uniform(sub, (batch, n))`` (through
+    ``uniform(sub, b, v)``) and follows the first in-edge whose running
+    weight sum passes it, or none.  A sample's frontier is one vertex,
+    so its hits are distinct and need no sort; they sit at their pairs'
+    places.  Returns (new, visited | new, any new, uniforms drawn,
+    pairs)."""
+    n, d = nbr.shape
+    b, v, on = _frontier_pairs(frontier, row_pairs, cap)
+    r = uniform(sub, b, v)                              # [cap]
+    chosen = jnp.sum(r[:, None] >= cumw[v], axis=1)     # d: none
+    u = nbr[v, jnp.minimum(chosen, d - 1)]
+    go = on & (chosen < in_deg[v]) & (u >= 0)
+    pairs = jnp.sum(on, dtype=jnp.int32)
+    new, visited, grew = _apply_updates(
+        frontier, visited, pairs, b, v, pairs, jnp.where(go, u, n), b, go,
+        block=block)
+    return new, visited, grew, pairs.astype(jnp.float32), pairs
 
 
 def _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
@@ -514,12 +576,39 @@ def _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
     if d == 0:          # edgeless graph: RRR(root) = {root}
         return visited0, _dense_counts(jnp.int32(0), n, d, batch,
                                        model=model, coin_chunk=coin_chunk)
-    valid = nbr >= 0
     chunk, n_chunks, d_pad = _coin_chunks(d, coin_chunk)
     plane = np.float32(coins_per_step(n, d, batch, model=model,
                                       coin_chunk=coin_chunk))
+    cap, block = _frontier_capacity(batch, model)
+    # The frontier step needs each uniform as a function of its index:
+    # a partitionable Threefry key (and, under IC, flat indices within
+    # uint32 words).
+    by_index = (_threefry_words(key) is not None
+                and jax.config.jax_threefry_partitionable)
 
-    if model == "IC":
+    def row_pairs_of(frontier):
+        return jnp.sum(bitset.popcount(frontier), axis=1)
+
+    if model == "LT":
+        if by_index:
+            def uniform(sub, b, v):
+                return _uniform_at(sub, *_wide_index(b, n, v))
+        else:
+            def uniform(sub, b, v):
+                return jax.random.uniform(sub, (batch, n))[b, v]
+        lt_step = functools.partial(
+            _lt_frontier_step, nbr=nbr, cumw=_running_sums(wt),
+            in_deg=jnp.sum(nbr >= 0, axis=1), uniform=uniform, cap=cap,
+            block=block)
+
+        def step(frontier, visited, sub):
+            new, visited, grew, drawn, pairs = lt_step(
+                frontier, visited, sub, row_pairs_of(frontier))
+            if by_index:
+                return new, visited, grew, drawn, jnp.bool_(True), pairs
+            return new, visited, grew, plane, jnp.bool_(False), \
+                jnp.int32(0)
+    else:
         prob_p = (jnp.pad(prob, ((0, 0), (0, d_pad - d)))
                   if d_pad != d else prob)
 
@@ -540,73 +629,53 @@ def _rrr_batch_packed(nbr, prob, wt, fwd_nbr, fwd_rslot, roots, key, *,
             mask0 = jnp.zeros((n, d_pad, bitset.num_words(batch)),
                               dtype=bitset.WORD_DTYPE)
             return lax.fori_loop(0, n_chunks, one, mask0)
-    else:  # LT live-edge selection mask
-        cumw = jnp.cumsum(wt, axis=1)                      # [n, d]
-        in_deg = jnp.sum(valid, axis=1)                    # [n]
 
-        def step_mask(sub):
-            r = jax.random.uniform(sub, (batch, n))        # same draw
-            chosen = jnp.sum(r[:, :, None] >= cumw[None], axis=-1)
+        def dense_step(frontier, visited, sub, _):
+            new, visited = _expand_packed(frontier, visited, fwd_nbr,
+                                          fwd_rslot, step_mask(sub),
+                                          kernel, gather=gather,
+                                          block_v=block_v)
+            return new, visited, jnp.any(new), plane, jnp.int32(0)
 
-            # sel[b, v, slot] = (chosen == slot) & (slot < in_deg[v]):
-            # the packed one-hot of the dense path's pick_nbr scatter
-            # (slot < in_deg implies nbr[v, slot] >= 0).
-            def one(c, m):
-                slots = c * chunk + jnp.arange(chunk)
-                sel = ((chosen[:, :, None] == slots[None, None]) &
-                       (slots[None, None] < in_deg[None, :, None]))
-                pk = _pack_batch_lane(sel, n, chunk, batch)
-                return lax.dynamic_update_slice(m, pk, (0, c * chunk, 0))
+        if by_index and n * chunk < 2 ** 32:
+            nbr_p = (jnp.pad(nbr, ((0, 0), (0, d_pad - d)),
+                             constant_values=-1) if d_pad != d else nbr)
+            sparse_step = functools.partial(
+                _frontier_step, nbr_p=nbr_p, prob_p=prob_p, chunk=chunk,
+                n_chunks=n_chunks, cap=cap, block=block)
 
-            mask0 = jnp.zeros((n, d_pad, bitset.num_words(batch)),
-                              dtype=bitset.WORD_DTYPE)
-            return lax.fori_loop(0, n_chunks, one, mask0)
-
-    def dense_step(frontier, visited, sub, _):
-        new, visited = _expand_packed(frontier, visited, fwd_nbr,
-                                      fwd_rslot, step_mask(sub), kernel,
-                                      gather=gather, block_v=block_v)
-        return new, visited, jnp.any(new), plane
-
-    # The frontier step needs each coin as a function of its index:
-    # IC under a partitionable Threefry key, flat indices within uint32
-    # words.
-    cap, block = _frontier_capacity(batch)
-    if (model == "IC" and _threefry_words(key) is not None
-            and jax.config.jax_threefry_partitionable
-            and n * chunk < 2 ** 32):
-        nbr_p = (jnp.pad(nbr, ((0, 0), (0, d_pad - d)), constant_values=-1)
-                 if d_pad != d else nbr)
-        sparse_step = functools.partial(
-            _frontier_step, nbr_p=nbr_p, prob_p=prob_p, chunk=chunk,
-            n_chunks=n_chunks, cap=cap, block=block)
-
-        def step(frontier, visited, sub):
-            row_pairs = jnp.sum(bitset.popcount(frontier), axis=1)
-            # float32 sums are exact up to 2**24 > cap and never fall
-            # back below cap once past it, where int32 could wrap
-            fits = jnp.sum(row_pairs, dtype=jnp.float32) <= cap
-            out = lax.cond(fits, sparse_step, dense_step, frontier,
-                           visited, sub, row_pairs)
-            return out + (fits,)
-    else:
-        def step(frontier, visited, sub):
-            return dense_step(frontier, visited, sub, None) + (
-                jnp.bool_(False),)
+            def step(frontier, visited, sub):
+                row_pairs = row_pairs_of(frontier)
+                # float32 sums are exact up to 2**24 > cap and never
+                # fall back below cap once past it, where int32 could
+                # wrap
+                fits = jnp.sum(row_pairs, dtype=jnp.float32) <= cap
+                new, visited, grew, drawn, pairs = lax.cond(
+                    fits, sparse_step, dense_step, frontier, visited, sub,
+                    row_pairs)
+                return new, visited, grew, drawn, fits, pairs
+        else:
+            def step(frontier, visited, sub):
+                new, visited, grew, drawn, pairs = dense_step(
+                    frontier, visited, sub, None)
+                return new, visited, grew, drawn, jnp.bool_(False), pairs
 
     def body(state):
         frontier, visited, k, live, counts = state
         k, sub = jax.random.split(k)
-        new, visited, live, drawn, sparse = step(frontier, visited, sub)
+        new, visited, live, drawn, sparse, pairs = step(frontier, visited,
+                                                        sub)
         counts = S1Counts(counts.bfs_steps + 1,
                           counts.frontier_steps + sparse.astype(jnp.int32),
-                          counts.coins_drawn + drawn)
+                          counts.coins_drawn + drawn,
+                          counts.frontier_pairs + pairs)
         return new, visited, k, live, counts
 
     def cond(state):
         return state[3] & (state[4].bfs_steps < max_steps)
 
-    zero = S1Counts(jnp.int32(0), jnp.int32(0), jnp.float32(0))
+    zero = S1Counts(jnp.int32(0), jnp.int32(0), jnp.float32(0),
+                    jnp.int32(0))
     _, visited, _, _, counts = jax.lax.while_loop(
         cond, body, (visited0, visited0, key, jnp.any(visited0), zero))
     return visited, counts

@@ -424,3 +424,142 @@ def test_first_above_matches_searchsorted(length):
     np.testing.assert_array_equal(
         np.minimum(np.asarray(got), length),
         np.searchsorted(cum, x, side="right"))
+
+
+# ---------------------------------------------------------------------
+# LT frontier step: every LT step of the packed engines draws one
+# uniform per frontier pair, bit-identical to the dense draw
+# ---------------------------------------------------------------------
+
+def _lt_tables(g, scale=1.0):
+    """The padded tables of ``g`` with its LT weights times ``scale``."""
+    nbr, prob, wt = padded_adjacency(g)
+    return nbr, prob, wt * np.float32(scale)
+
+
+def _lt_tie_tables(key, batch):
+    """0 <- 1, 0 <- 2, 1 <- 3: vertex 0's first running weight sum is
+    exactly the uniform that sample 0 draws at 0 in the first step, so
+    that sample follows the second in-edge (the first sum does not pass
+    its draw)."""
+    g = _p1_graph([1, 2, 3], [0, 0, 1], 4)
+    nbr, prob, wt = padded_adjacency(g)
+    _, sub = jax.random.split(key)
+    r = float(jax.random.uniform(sub, (batch, 4))[0, 0])
+    wt = wt.at[0, 0].set(r).at[0, 1].set(np.float32(1.0) - np.float32(r))
+    return g, (nbr, prob, wt)
+
+
+def _lt_cases(key):
+    chain = _chain_graph(48)
+    # vertices 0-9 have no in-edge; 10-19 one to three of them
+    src = np.array([0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 0, 5, 9, 2])
+    dst = np.array([10, 11, 12, 13, 14, 15, 16, 17, 18, 19, 11, 12, 12, 19])
+    sparse_in = from_edge_list(src, dst, 20)
+    deep = _deep_graph()
+    tie_g, tie_tab = _lt_tie_tables(key, 32)
+    return {
+        "parity_graphs": [(g, padded_adjacency(g), 64, None)
+                          for g in _parity_graphs()],
+        "unaligned_batch": [(deep, padded_adjacency(deep), b, None)
+                            for b in (40, 72)],
+        # 47 steps from the last vertex to the first, past 32
+        "deep_chain": [(chain, padded_adjacency(chain), 32,
+                        np.full(32, 47, np.int32))],
+        "in_degree_0": [(sparse_in, padded_adjacency(sparse_in), 64, None)],
+        # in-weights summing to 0.6: a draw past it selects no in-edge
+        "short_rows": [(chain, _lt_tables(chain, 0.6), 64,
+                        np.full(64, 47, np.int32)),
+                       (deep, _lt_tables(deep, 0.6), 64, None)],
+        "draw_on_a_sum": [(tie_g, tie_tab, 32, np.zeros(32, np.int32))],
+    }
+
+
+@pytest.mark.parametrize("kernel", (False, True))
+@pytest.mark.parametrize("case", ("parity_graphs", "unaligned_batch",
+                                  "deep_chain", "in_degree_0", "short_rows",
+                                  "draw_on_a_sum"))
+def test_lt_frontier_steps_bit_identical_to_dense(case, kernel):
+    """Every LT step of the packed engine is a frontier step, and its
+    incidence is the dense sampler's bit for bit; each frontier pair
+    drew one uniform, and every sampled pair was a frontier pair once."""
+    key = jax.random.key(5)
+    for g, (nbr, prob, wt), batch, roots in _lt_cases(key)[case]:
+        n = g.num_vertices
+        fwd = padded_forward_adjacency(g)
+        if roots is None:
+            roots = jax.random.randint(jax.random.key(7), (batch,), 0, n)
+        dense, dcount = rrr_mod._rrr_batch_dense(
+            nbr, prob, wt, roots, key, model="LT", max_steps=64,
+            coin_chunk=32)
+        packed, count = jax.jit(functools.partial(
+            rrr_mod._rrr_batch_packed, model="LT", max_steps=64,
+            coin_chunk=32, kernel=kernel))(nbr, prob, wt, *fwd, roots, key)
+        np.testing.assert_array_equal(
+            np.asarray(bitset.pack_bool_matrix(dense.T)),
+            np.asarray(packed))
+        steps = int(count.bfs_steps)
+        assert steps == int(dcount.bfs_steps) < 64
+        assert int(count.frontier_steps) == steps
+        pairs = int(np.sum(np.asarray(bitset.popcount(packed))))
+        assert int(count.frontier_pairs) == pairs
+        assert float(count.coins_drawn) == pairs
+        sizes = np.asarray(dense).sum(axis=1)
+        if case == "deep_chain":
+            assert steps == 48 and (sizes == 48).all()
+        if case == "short_rows":
+            assert sizes.min() < sizes.max()
+        if case == "draw_on_a_sum":
+            np.testing.assert_array_equal(np.asarray(dense)[0],
+                                          [True, False, True, False])
+
+
+def test_lt_dense_fallback_without_index_draws():
+    """With partitionable Threefry off, uniforms cannot be computed by
+    index: the LT step reads its pairs' draws out of the whole plane,
+    counts as a dense step, and gives the same sets."""
+    g = _deep_graph()
+    nbr, prob, wt = padded_adjacency(g)
+    fwd = padded_forward_adjacency(g)
+    roots = jax.random.randint(jax.random.key(7), (64,), 0, 60)
+    key = jax.random.key(5)
+    with jax.threefry_partitionable(False):
+        dense, _ = rrr_mod._rrr_batch_dense(nbr, prob, wt, roots, key,
+                                            model="LT", max_steps=64,
+                                            coin_chunk=32)
+        packed, count = rrr_mod._rrr_batch_packed(
+            nbr, prob, wt, *fwd, roots, key, model="LT", max_steps=64,
+            coin_chunk=32, kernel=False)
+    np.testing.assert_array_equal(
+        np.asarray(bitset.pack_bool_matrix(dense.T)), np.asarray(packed))
+    steps = int(count.bfs_steps)
+    assert int(count.frontier_steps) == 0 == int(count.frontier_pairs)
+    assert float(count.coins_drawn) == steps * 64 * 60
+
+
+@pytest.mark.parametrize("model,want", (("IC", (2048, 1024)),
+                                        ("LT", (1024, 1024)),
+                                        ("LT", (40, 40))))
+def test_frontier_capacity(model, want):
+    """Twice the batch under IC, the batch under LT, in whole update
+    blocks."""
+    batch = want[0] if model == "LT" else want[0] // 2
+    assert rrr_mod._frontier_capacity(batch, model) == want
+
+
+def test_running_sums_add_left_to_right():
+    """The LT thresholds are float32 sums taken slot by slot from the
+    left, on rows where another order rounds differently."""
+    rng = np.random.default_rng(0)
+    wt = rng.uniform(0.1, 1.0, (500, 21))
+    wt = (wt / wt.sum(axis=1, keepdims=True)).astype(np.float32)
+    want = np.zeros_like(wt)
+    acc = np.zeros(500, np.float32)
+    for j in range(21):
+        acc = acc + wt[:, j]
+        want[:, j] = acc
+    got = np.asarray(jax.jit(rrr_mod._running_sums)(jnp.asarray(wt)))
+    np.testing.assert_array_equal(got, want)
+    # the same weights added from the right end elsewhere
+    backwards = np.cumsum(wt[:, ::-1], axis=1, dtype=np.float32)
+    assert not np.array_equal(backwards[:, -1], want[:, -1])
