@@ -191,7 +191,7 @@ def run_service(g, *, theta0: int, slab: int, batches: int, batch: int,
                 k_max: int, seed: int) -> dict:
     """The query service as ``launch/serve.py --check`` drives it:
     resident pool of ``theta0`` samples per OPIM half, ``batches``
-    vmapped batches of mixed-k, partly seed-constrained queries, each
+    batches of mixed-k, partly seed-constrained queries, each
     answer bit-identical to the sequential ``answer_one``."""
     service = InfluenceService(
         g, jax.random.key(seed), theta0=theta0, max_theta=theta0,

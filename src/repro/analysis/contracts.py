@@ -446,12 +446,13 @@ def build_registry() -> Tuple[KernelContract, ...]:
         KernelContract(
             name="service.batched", family="service",
             description="batched query solve: B concurrent "
-                        "seed-constrained queries in ONE vmapped "
-                        "launch (grid carries the batch axis)",
+                        "seed-constrained queries in ONE launch with no "
+                        "grid (each streamed pool tile sweeps every "
+                        "query; no batch grid re-reads the pool)",
             build=_build_maxcover_batch(4),
             expected_launches=serve["launches"],
             expect_in_loop=serve["in_loop"],
-            expected_grid=(4,),
+            expected_grid=(),
             dtype_whitelist=wl(serve),
             expected_aliases=serve["aliases"]),
     )

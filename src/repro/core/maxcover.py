@@ -55,6 +55,9 @@ class CoverSolution(NamedTuple):
     coverage: jnp.ndarray   # int32 [] total bits covered
     gains: jnp.ndarray      # int32 [k] marginal gain at each pick
     tiles_swept: jnp.ndarray  # int32 [] row tiles the picks swept
+    # int32 [] row tiles the solve read from the pool: ``tiles_swept``
+    # for one query; for a batch its one count (see greedy_maxcover_batch)
+    tile_dmas: jnp.ndarray
 
 
 def resolve_solver(solver: str | None,
@@ -117,14 +120,25 @@ def greedy_maxcover_batch(rows: jnp.ndarray, excluded: jnp.ndarray,
                           k: int,
                           solver: str | None = None) -> CoverSolution:
     """Batched greedy max-k-cover: B seed-constrained queries against
-    ONE shared row pool in a single vmapped solve.
+    ONE shared row pool in a single solve.
 
-    rows: uint32 [n, W] shared packed pool (``in_axes=None`` — the row
-    stream is not replicated per query); excluded: int32 [B, E] per-
+    rows: uint32 [n, W] shared packed pool; excluded: int32 [B, E] per-
     query exclusion ids (-1 pads).  Returns a ``CoverSolution`` whose
-    every leaf has a leading [B] axis; slice b is bit-identical to
-    ``greedy_maxcover(rows, k, solver=..., excluded=excluded[b])`` for
-    all four solvers.  Mixed per-query k is handled above this layer
+    every leaf but ``tile_dmas`` has a leading [B] axis; slice b is
+    bit-identical to ``greedy_maxcover(rows, k, solver=...,
+    excluded=excluded[b])`` for all four solvers, ``tiles_swept``
+    included.
+
+    ``solver="resident"`` is one kernel launch for the whole batch
+    (``kernels/greedy_pick``): each pick streams every row tile from HBM
+    once and sweeps it against all B queries, so the pool is read k
+    times, not B*k times, and ``tile_dmas`` is the kernel's own count
+    of the row tiles it read (``k * row tiles``).  The other solvers
+    are the single-query solve under ``jax.vmap`` (the lazy kernel's
+    batch becomes a grid, each query streaming the pool itself), and
+    ``tile_dmas`` sums the queries' own.
+
+    Mixed per-query k is handled above this layer
     (``repro.core.service``) by solving at max(k) and truncating —
     greedy picks are prefix-consistent, so the truncation is exact.
     """
@@ -141,7 +155,17 @@ def _greedy_maxcover(rows: jnp.ndarray, excluded: jnp.ndarray, k: int,
 @functools.partial(jax.jit, static_argnames=("k", "solver"))
 def _greedy_maxcover_batch(rows: jnp.ndarray, excluded: jnp.ndarray,
                            k: int, solver: str) -> CoverSolution:
-    return jax.vmap(lambda ex: _solve_one(rows, ex, k, solver))(excluded)
+    if solver == "resident":
+        from repro.kernels import ops as kops
+        seeds, sel_rows, covered, gains, dmas = \
+            kops.greedy_maxcover_resident_batch(rows, k, excluded)
+        full = jnp.full((excluded.shape[0],),
+                        full_sweep_tiles(rows.shape[0], k), jnp.int32)
+        return CoverSolution(seeds, sel_rows, covered,
+                             bitset.coverage_size(covered), gains, full,
+                             dmas)
+    sol = jax.vmap(lambda ex: _solve_one(rows, ex, k, solver))(excluded)
+    return sol._replace(tile_dmas=jnp.sum(sol.tile_dmas))
 
 
 def full_sweep_tiles(n: int, k: int) -> int:
@@ -155,7 +179,8 @@ def full_sweep_tiles(n: int, k: int) -> int:
 def _solve_one(rows: jnp.ndarray, excluded: jnp.ndarray, k: int,
                solver: str) -> CoverSolution:
     """One greedy solve (trace-level body — vmapped by the batch entry
-    point, so everything here must be vmap-compatible)."""
+    point for every solver but "resident", so everything here must be
+    vmap-compatible)."""
     n, w = rows.shape
     full = jnp.int32(full_sweep_tiles(n, k))
 
@@ -164,7 +189,8 @@ def _solve_one(rows: jnp.ndarray, excluded: jnp.ndarray, k: int,
         seeds, sel_rows, covered, gains = kops.greedy_maxcover_resident(
             rows, k, excluded)
         return CoverSolution(seeds, sel_rows, covered,
-                             bitset.coverage_size(covered), gains, full)
+                             bitset.coverage_size(covered), gains, full,
+                             full)
 
     if solver == "lazy":
         from repro.kernels import ops as kops
@@ -172,7 +198,8 @@ def _solve_one(rows: jnp.ndarray, excluded: jnp.ndarray, k: int,
         seeds, sel_rows, covered, gains, swept = kops.greedy_maxcover_lazy(
             rows, k, excluded)
         return CoverSolution(seeds, sel_rows, covered,
-                             bitset.coverage_size(covered), gains, swept)
+                             bitset.coverage_size(covered), gains, swept,
+                             swept)
 
     if solver == "fused":
         from repro.kernels import ops as kops
@@ -210,7 +237,7 @@ def _solve_one(rows: jnp.ndarray, excluded: jnp.ndarray, k: int,
     covered, seeds, sel_rows, picked, gains = jax.lax.fori_loop(
         0, k, body, (covered, seeds, sel_rows, picked, gains))
     return CoverSolution(seeds, sel_rows, covered,
-                         bitset.coverage_size(covered), gains, full)
+                         bitset.coverage_size(covered), gains, full, full)
 
 
 def _popcount_words(words) -> int:

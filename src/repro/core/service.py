@@ -6,11 +6,12 @@ across a single max-k-cover solve.  This module inverts that for the
 millions-of-users scenario: the packed ``uint32 [n, W]`` RRR incidence
 stays *resident* as a sketch pool (two OPIM halves — R1 for selection,
 R2 for validation) and MANY concurrent ``(k, seed-constraint, budget)``
-queries are answered against the same pool with ONE vmapped solve over
-the sender quad — the row stream is shared (``in_axes=None``) while
-only the tiny per-query state (covered words + k seed slots + E
-exclusion slots) fans out, following the sketch-sharing design of
-Cohen et al. (arXiv:1408.6282).
+queries are answered against the same pool with ONE batched solve over
+the sender quad — with the resident solver one kernel launch that
+streams the pool once per pick for the whole batch, while only the
+small per-query state (covered words, k selected rows, k seed and gain
+slots, E exclusion slots) fans out, following the sketch-sharing
+design of Cohen et al. (arXiv:1408.6282).
 
 Pool lifecycle
 --------------
@@ -50,6 +51,7 @@ query.
 """
 from __future__ import annotations
 
+import collections
 import math
 import time
 from typing import Callable, NamedTuple, Optional, Sequence, Tuple
@@ -70,9 +72,10 @@ from repro.runtime.spans import span
 
 
 # Static contract (proved by repro.analysis on a canonical fixture):
-# B concurrent seed-constrained queries batch into ONE vmapped launch
-# whose grid carries the batch axis — the sketch pool itself is shared
-# (in_axes=None), so the launch count must not scale with B.
+# B concurrent seed-constrained queries batch into ONE launch with no
+# grid — the kernel sweeps each streamed tile of the shared sketch pool
+# against every query, so neither the launch count nor the pool reads
+# scale with B.
 CONTRACT = dict(
     family="service",
     launches=1,
@@ -80,6 +83,22 @@ CONTRACT = dict(
     dtypes=("bool", "int32", "uint32"),
     aliases=(),
 )
+
+
+class Solve(NamedTuple):
+    """One batched solve as :func:`answer_batch` dispatched it."""
+    at: float               # time.perf_counter() at dispatch
+    queries: int            # B
+    k_max: int              # picks the solve made for every query
+    row_tiles: int          # row tiles of the pool one pick sweeps
+    tile_dmas: jax.Array    # int32 []: row tiles the solve read from
+    #   the pool (``CoverSolution.tile_dmas``), left on the device
+
+
+SOLVES: collections.deque = collections.deque(maxlen=1 << 12)
+"""The latest batched solves, oldest first: what a reader needs to set
+the pool reads of a batch against one stream per query and pick,
+without a host transfer on the answer path."""
 
 
 class EmptyPoolError(RuntimeError):
@@ -402,11 +421,15 @@ def restore_pool(store, g: CSRGraph, *,
 # ---------------------------------------------------------------------
 
 def per_query_state_bytes(words: int, k: int, excl: int) -> int:
-    """VMEM-resident per-query solve state: covered words + k seed and
-    gain slots + E exclusion slots.  The [n, W] row pool is SHARED
-    across the batch (amortized, not per-query) — this is the number
-    the batched engine fans out per concurrent query."""
-    return 4 * words + 4 * k + 4 * k + 4 * excl
+    """VMEM-resident per-query state of the batched resident solve:
+    covered words + k selected rows + k seed and gain slots + E
+    exclusion slots, all resident at once for the whole batch.  The
+    [n, W] row pool is SHARED across the batch (streamed once per pick,
+    not per query) — this is what each concurrent query adds."""
+    return 4 * (words + k * words + 2 * k + excl)
+
+
+EXCL_SLOTS = 8
 
 
 def _query_arrays(queries: Sequence[Query], n: int, theta: int):
@@ -420,7 +443,11 @@ def _query_arrays(queries: Sequence[Query], n: int, theta: int):
             if not (0 <= int(v) < n):
                 raise ValueError(f"excluded id {v} out of range [0, {n})")
     k_max = max(q.k for q in queries)
-    e_max = max(1, max(len(q.excluded) for q in queries))
+    # Exclusion slots come in whole groups of EXCL_SLOTS (-1 pads), so
+    # batches whose longest exclusion lists differ by a few ids share
+    # one compiled solve instead of compiling one each.
+    longest = max(len(q.excluded) for q in queries)
+    e_max = EXCL_SLOTS * max(1, -(-longest // EXCL_SLOTS))
     excl = np.full((len(queries), e_max), -1, np.int32)
     for b, q in enumerate(queries):
         if q.excluded:
@@ -490,15 +517,18 @@ def _answers(pool: SketchPool, queries: Sequence[Query], seeds_t,
 def answer_batch(pool: SketchPool, queries: Sequence[Query], *,
                  solver: str = "resident", delta: float = 1.0 / 128.0,
                  alpha: Optional[float] = None) -> list[Answer]:
-    """Answer B concurrent queries with ONE vmapped solve over the
+    """Answer B concurrent queries with ONE batched solve over the
     shared R1 pool (plus one vmapped truncation/validation epilogue).
 
     Bit-identical per query to :func:`answer_one` for every solver in
     the quad: the batch solves every query at ``k_max = max(k)`` and
-    truncates — greedy prefix-consistency makes that exact — while the
-    [n, W] row stream is shared across the batch (``in_axes=None``)
-    and only the O(W + k + E) per-query state fans out
-    (:func:`per_query_state_bytes`).
+    truncates — greedy prefix-consistency makes that exact.  With the
+    resident solver the solve is one kernel launch that streams each
+    row tile of the [n, W] pool once per pick for the whole batch and
+    sweeps it against every query's O(k*W + E) state
+    (:func:`per_query_state_bytes`); the other solvers run the
+    single-query solve under ``jax.vmap``.  Each solve leaves a
+    :class:`Solve` in ``SOLVES``.
     """
     if pool.theta == 0:
         raise EmptyPoolError(
@@ -514,6 +544,9 @@ def answer_batch(pool: SketchPool, queries: Sequence[Query], *,
     with span("service.dispatch"):
         sol = maxcover.greedy_maxcover_batch(pool.r1, excl, k_max,
                                              solver=solver)
+        SOLVES.append(Solve(time.perf_counter(), len(queries), k_max,
+                            maxcover.full_sweep_tiles(pool.n, 1),
+                            sol.tile_dmas))
         out = _finalize_batch(sol.seeds, sol.rows, sol.gains, ks,
                               budget_cov, pool.r2)
     with span("service.wait"):
@@ -691,7 +724,7 @@ class InfluenceService:
 
     def answer(self, tickets: Sequence[Ticket]) -> list[Answer]:
         """Answer a batch of tickets; tickets sharing a generation are
-        answered by one vmapped solve against that generation's pool
+        answered by one batched solve against that generation's pool
         (stale generations raise, draining ones complete).  Returns
         answers in ticket order.
 

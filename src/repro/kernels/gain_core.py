@@ -44,7 +44,15 @@ def gain_tile_sum(x: jnp.ndarray, cover: jnp.ndarray) -> jnp.ndarray:
     Callers accumulate this across word tiles; the keepdims shape is
     the [rows, 1] accumulator layout all kernels share.
     """
-    return jnp.sum(andnot_popcount(x, cover), axis=-1, keepdims=True)
+    return uncovered_tile_sum(x, ~cover)
+
+
+def uncovered_tile_sum(x: jnp.ndarray, uncovered: jnp.ndarray) -> jnp.ndarray:
+    """``gain_tile_sum`` given the cover's complement ``~cover``, for a
+    caller that sweeps many tiles against one cover and hoists the
+    complement out of its loop."""
+    return jnp.sum(jax.lax.population_count(x & uncovered).astype(jnp.int32),
+                   axis=-1, keepdims=True)
 
 
 def effective_block(size: int, block: int, floor: int) -> int:

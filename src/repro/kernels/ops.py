@@ -15,7 +15,8 @@ from repro.kernels.bucket import bucket_gains_pallas
 from repro.kernels.bucket_insert import (bucket_insert_chunk_pallas,
                                          bucket_insert_stream_pallas)
 from repro.kernels.coverage import marginal_gain_pallas
-from repro.kernels.greedy_pick import greedy_maxcover_resident_pallas
+from repro.kernels.greedy_pick import (
+    greedy_maxcover_resident_batch_pallas, greedy_maxcover_resident_pallas)
 from repro.kernels.lazy_greedy import greedy_maxcover_lazy_pallas
 from repro.kernels.rrr_expand import (rrr_expand_step_pallas,
                                       rrr_expand_step_resident_pallas)
@@ -68,16 +69,16 @@ def greedy_maxcover_lazy(rows: jnp.ndarray, k: int,
 def greedy_maxcover_resident_batch(rows: jnp.ndarray, k: int,
                                    excluded: jnp.ndarray):
     """Batched-query entry point: B concurrent seed-constrained solves
-    over ONE shared [n, W] row pool in a single vmapped resident
-    kernel.  ``excluded`` is int32 [B, E] (-1 pads); the row stream is
-    NOT replicated per query (``in_axes=None``) — only the tiny
-    VMEM-resident query state (covered words + k seed slots + E
-    exclusion slots) fans out across the batch.  Returns the resident
-    tuple with a leading [B] axis, each slice bit-identical to the
-    sequential per-query call."""
-    return jax.vmap(
-        lambda ex: greedy_maxcover_resident_pallas(
-            rows, k, ex, interpret=_interpret()))(excluded)
+    over ONE shared [n, W] row pool in a single resident launch.
+    ``excluded`` is int32 [B, E] (-1 pads).  Each pick streams every
+    row tile of the pool from HBM once and sweeps it against all B
+    queries' VMEM-resident state (covered words, k selected rows, k seed
+    and gain slots, E exclusion slots), so the pool is read k times for the batch, not
+    B*k times.  Returns the resident tuple with a leading [B] axis,
+    each slice bit-identical to the sequential per-query call, plus
+    the kernel's count of the row tiles it DMA'd (int32 [])."""
+    return greedy_maxcover_resident_batch_pallas(rows, k, excluded,
+                                                 interpret=_interpret())
 
 
 def greedy_maxcover_lazy_batch(rows: jnp.ndarray, k: int,

@@ -31,7 +31,11 @@ Budget model (all word-sized = 4-byte units):
             flattening pad, or 2·BV·(Wp+2) gather words resident.
   senders   (``greedy_pick`` / ``lazy_greedy``)  the [2, BV, Wp] row
             double buffer; BV=128 is the analytic default and the
-            tuned table may override it.
+            tuned table may override it.  A batched ``greedy_pick``
+            launch adds per query (k + 1 + 8)·Wp + 2·k + E words
+            (selected rows, cover, winner block, seed/gain and
+            exclusion slots); the solved-for term is the queries one
+            launch holds.
 
 ``vmem_budget_bytes=None`` everywhere means "the default budget",
 overridable process-wide via ``REPRO_VMEM_BUDGET_BYTES`` (how the
@@ -133,6 +137,17 @@ def auto_block_v(family: str, default: int = DEFAULT_BLOCK_V,
     geometry.
     """
     return tuned_value(family, "block_v", backend) or default
+
+
+# -------------------------------------------------------------- senders
+def sender_batch_queries(k: int, excl: int, wp: int, bv: int) -> int:
+    """Queries one batched ``greedy_pick`` launch can hold (>= 1): the
+    budget less the [2, BV, Wp] row double buffer, over each query's
+    resident words."""
+    per_query = WORD_BYTES * ((k + 1 + gain_core.SUBLANE) * wp
+                              + 2 * k + excl)
+    avail = budget_bytes() - WORD_BYTES * 2 * bv * wp
+    return int(max(1, avail // per_query))
 
 
 # ------------------------------------------------------------- receiver

@@ -6,7 +6,7 @@ resident sketch pool (``repro.core.service``).
 
 Generates a deterministic trace of (k, seed-constraint, budget)
 queries, admits them in batches of ``--batch`` through
-:class:`~repro.core.service.InfluenceService` (ONE vmapped solve per
+:class:`~repro.core.service.InfluenceService` (ONE batched solve per
 batch over the shared pool), and reports throughput.  ``--check``
 additionally replays every query through the sequential
 ``answer_one`` reference and exits non-zero unless the batched answers
@@ -255,7 +255,7 @@ def main(argv=None):
     ap.add_argument("--model", default="IC", choices=("IC", "LT"))
     ap.add_argument("--queries", type=int, default=16)
     ap.add_argument("--batch", type=int, default=8,
-                    help="concurrent queries per vmapped solve")
+                    help="concurrent queries per batched solve")
     ap.add_argument("--k-max", type=int, default=8)
     ap.add_argument("--solver", default="resident",
                     choices=("scan", "fused", "resident", "lazy"))

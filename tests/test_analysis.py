@@ -181,6 +181,19 @@ def test_registry_clean_pass_and_family_coverage():
     assert {r.family for r in reports} == set(contracts.FAMILIES)
 
 
+@pytest.mark.parametrize("batch", [1, 4, 8])
+def test_service_batched_contract_one_gridless_launch(batch):
+    """The service's batched solve is one launch outside any loop with
+    no grid, whatever B: the batch is swept inside the kernel, not run
+    as a grid that streams the pool once per query."""
+    c = contracts.contracts_by_name()["service.batched"]
+    assert c.expected_grid == () and c.expected_launches == 1
+    fn, args = contracts._build_maxcover_batch(batch)()
+    (site,) = jaxpr_check.launch_sites(jax.make_jaxpr(fn)(*args))
+    assert site.grid == () and not site.in_loop
+    assert site.name == "greedy_pick_resident"
+
+
 def test_one_contract_through_hlo_pass():
     """One registry entry end to end with the compile-based HLO pass
     (the CI job runs all of them; keeping one in tier-1 pins the

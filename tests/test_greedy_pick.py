@@ -224,3 +224,165 @@ def test_vmapped_solver_parity():
                                       np.asarray(want.seeds), solver)
         np.testing.assert_array_equal(np.asarray(got.gains),
                                       np.asarray(want.gains), solver)
+
+
+# ------------------------------------------------- shared-stream batch
+def _tie_rows(n, w):
+    """Rows 5, 140 and 290 identical and the largest, one in each
+    128-row tile: the lowest index must win, and the others then gain
+    nothing."""
+    rows = np.asarray(_random_rows(n, w, seed=17)) & np.uint32(0x0F0F0F0F)
+    rows[[5, 140, 290]] = np.uint32(0xFFFFFFFF)
+    return jnp.asarray(rows)
+
+
+def _exhausted_rows(n, w):
+    rng = np.random.default_rng(3)
+    dense = rng.random((n, w * 32)) < 0.4
+    dense[4] = dense[0]          # duplicate
+    dense[5] = False             # empty row
+    return bitset.pack_bool_matrix(jnp.asarray(dense))
+
+
+def _exclusions(kind, rows, b, k):
+    """int32 [b, E] exclusion ids of one case (-1 pads)."""
+    n = rows.shape[0]
+    e = np.full((b, 4), -1, np.int32)
+    if kind == "overlapping":    # query q shares ids with q - 1
+        for q in range(b):
+            e[q, :3] = [(7 * q) % n, (7 * q + 7) % n, (3 * q + 1) % n]
+    elif kind == "best":         # each query loses some of the best rows
+        top = np.asarray(maxcover.greedy_maxcover(rows, 4,
+                                                  solver="scan").seeds)
+        for q in range(b):
+            picked = [int(s) for s in top[q % 4:] if s >= 0][:2]
+            e[q, :len(picked)] = picked
+    elif kind == "tie":          # query q excludes the first q tied rows
+        for q in range(b):
+            ids = [5, 140][:q % 3]
+            e[q, :len(ids)] = ids
+    return jnp.asarray(e)
+
+
+# (batch, n, w, k, rows, exclusions): n = 300 and 260 are not whole
+# 128-row tiles, w = 130 is not a whole 128-word lane block, k = 10 on
+# six rows runs past the last positive gain.
+BATCH_CASES = {
+    "b1-plain": (1, 37, 3, 5, "random", "none"),
+    "b3-empty": (3, 300, 5, 6, "random", "none"),
+    "b8-empty": (8, 100, 7, 3, "random", "none"),
+    "b3-overlap-ragged-n": (3, 300, 5, 6, "random", "overlapping"),
+    "b8-overlap-ragged-w": (8, 300, 130, 4, "random", "overlapping"),
+    "b1-best-excluded": (1, 260, 7, 5, "random", "best"),
+    "b8-best-excluded": (8, 260, 7, 5, "random", "best"),
+    "b3-exhausted": (3, 6, 2, 10, "exhausted", "overlapping"),
+    "b8-exhausted": (8, 6, 2, 10, "exhausted", "none"),
+    "b1-tie-across-tiles": (1, 300, 4, 4, "tie", "none"),
+    "b3-tie-across-tiles": (3, 300, 4, 4, "tie", "tie"),
+    "b8-tie-across-tiles": (8, 300, 4, 4, "tie", "tie"),
+}
+
+
+def _batch_case(name):
+    b, n, w, k, rows_kind, excl_kind = BATCH_CASES[name]
+    rows = {"random": lambda: _random_rows(n, w, seed=n + w + b),
+            "exhausted": lambda: _exhausted_rows(n, w),
+            "tie": lambda: _tie_rows(n, w)}[rows_kind]()
+    return rows, _exclusions(excl_kind, rows, b, k), k
+
+
+@pytest.mark.parametrize("case", sorted(BATCH_CASES))
+def test_shared_stream_kernel_matches_single_and_scan(case):
+    """The shared-stream kernel (one launch, each row tile DMA'd once
+    per pick for all B queries) equals, query by query, the
+    single-query resident kernel and the scan solver on seeds, rows,
+    covered and gains, across tile boundaries (128-row tiles pinned),
+    and the batched solve keeps scan's ``tiles_swept``."""
+    from repro.kernels import greedy_pick
+
+    rows, excl, k = _batch_case(case)
+    seeds, sel, cov, gains, _ = \
+        greedy_pick.greedy_maxcover_resident_batch_pallas(
+            rows, k, excl, block_v=128, interpret=True)
+    batch = maxcover.greedy_maxcover_batch(rows, excl, k,
+                                           solver="resident")
+    for q in range(excl.shape[0]):
+        one = greedy_pick.greedy_maxcover_resident_pallas(
+            rows, k, excl[q], block_v=128, interpret=True)
+        scan = maxcover.greedy_maxcover(rows, k, solver="scan",
+                                        excluded=excl[q])
+        got = (seeds[q], sel[q], cov[q], gains[q])
+        for field, g, o, s in zip(("seeds", "rows", "covered", "gains"),
+                                  got, one, (scan.seeds, scan.rows,
+                                             scan.covered, scan.gains)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(o),
+                                          err_msg=f"{case} q{q} {field}")
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(s),
+                                          err_msg=f"{case} q{q} {field}")
+        for field in ("seeds", "rows", "covered", "gains", "coverage",
+                      "tiles_swept"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(batch, field)[q]),
+                np.asarray(getattr(scan, field)),
+                err_msg=f"{case} q{q} batched {field}")
+    if case.endswith("tie-across-tiles"):
+        want = [5, 140, 290]
+        for q in range(excl.shape[0]):
+            assert int(seeds[q, 0]) == want[q % 3]
+    if case.endswith("exhausted"):
+        assert np.all(np.asarray(seeds)[:, -1] == -1)
+        assert np.all(np.asarray(gains)[:, -1] == 0)
+
+
+@pytest.mark.parametrize("b", [1, 3, 8])
+def test_shared_stream_tile_dmas_once_per_pick(b):
+    """The kernel's own count of the row tiles it DMA'd is k x row
+    tiles for the whole batch, whatever B: one pool stream per pick."""
+    from repro.kernels import greedy_pick, lazy_greedy
+
+    n, k = 300, 5
+    rows = _random_rows(n, 4, seed=b)
+    excl = jnp.full((b, 2), -1, jnp.int32)
+    *_, dmas = greedy_pick.greedy_maxcover_resident_batch_pallas(
+        rows, k, excl, block_v=128, interpret=True)
+    assert lazy_greedy.num_row_tiles(n, block_v=128) == 3
+    assert int(dmas) == k * 3
+    sol = maxcover.greedy_maxcover_batch(rows, excl, k, solver="resident")
+    assert int(sol.tile_dmas) == maxcover.full_sweep_tiles(n, k)
+    assert np.asarray(sol.tiles_swept).tolist() == (
+        [maxcover.full_sweep_tiles(n, k)] * b)
+    scan = maxcover.greedy_maxcover_batch(rows, excl, k, solver="scan")
+    assert int(scan.tile_dmas) == b * maxcover.full_sweep_tiles(n, k)
+
+
+def test_shared_stream_batch_past_vmem_budget_splits(monkeypatch):
+    """A batch whose state outgrows one launch's VMEM budget is split
+    into as few launches as fit, each streaming the pool once per pick;
+    the answers stay those of the scan solver."""
+    from repro.analysis import jaxpr_check
+    from repro.kernels import greedy_pick, lazy_greedy, vmem_budget
+
+    n, w, k, b = 301, 9, 3, 8
+    wp, bv = 128, 128
+    per_query = 4 * ((k + 1 + 8) * wp + 2 * k + 2)
+    monkeypatch.setenv("REPRO_VMEM_BUDGET_BYTES",
+                       str(4 * 2 * bv * wp + 3 * per_query))
+    assert vmem_budget.sender_batch_queries(k, 2, wp, bv) == 3
+    rows = _random_rows(n, w, seed=4)
+    excl = _exclusions("overlapping", rows, b, k)[:, :2]
+    def fn(r, e):
+        return greedy_pick.greedy_maxcover_resident_batch_pallas(
+            r, k, e, block_v=bv, interpret=True)
+    sites = jaxpr_check.launch_sites(jax.make_jaxpr(fn)(rows, excl))
+    assert len(sites) == 3 and all(s.grid == () for s in sites)
+    seeds, _, cov, gains, dmas = fn(rows, excl)
+    assert int(dmas) == 3 * k * lazy_greedy.num_row_tiles(n, block_v=bv)
+    for q in range(b):
+        scan = maxcover.greedy_maxcover(rows, k, solver="scan",
+                                        excluded=excl[q])
+        np.testing.assert_array_equal(np.asarray(seeds[q]),
+                                      np.asarray(scan.seeds))
+        np.testing.assert_array_equal(np.asarray(gains[q]),
+                                      np.asarray(scan.gains))
+        np.testing.assert_array_equal(np.asarray(cov[q]),
+                                      np.asarray(scan.covered))

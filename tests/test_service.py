@@ -52,7 +52,7 @@ TRACE = [
 
 @pytest.mark.parametrize("solver", maxcover.SOLVERS)
 def test_batch_bit_identical_to_sequential(pool, solver):
-    """B=8 concurrent queries in ONE vmapped solve == the sequential
+    """B=8 concurrent queries in ONE batched solve == the sequential
     answer_one reference, bit-for-bit, on every solver — with mixed
     per-query k and a non-word-aligned n=37."""
     batch = svc.answer_batch(pool, TRACE, solver=solver)
@@ -66,6 +66,22 @@ def test_batch_bit_identical_to_sequential(pool, solver):
         assert a.sigma_upper == one.sigma_upper
         assert a.guarantee == one.guarantee
         assert a.certified == one.certified
+
+
+def test_exclusion_widths_share_one_compiled_solve(pool):
+    """Exclusion slots come in groups of EXCL_SLOTS: batches whose
+    longest lists differ within a group solve at one width and the
+    second compiles no new solve; a longer list takes the next group."""
+    slots = svc.EXCL_SLOTS
+    one = [Query(k=3, excluded=(1,)), Query(k=3)]
+    five = [Query(k=3, excluded=(0, 4, 9, 2, 5)), Query(k=3)]
+    many = [Query(k=3, excluded=tuple(range(2, 3 + slots)))]
+    assert [svc._query_arrays(b, pool.n, pool.theta)[1].shape[1]
+            for b in (one, five, many)] == [slots, slots, 2 * slots]
+    svc.answer_batch(pool, one)
+    compiled = maxcover._greedy_maxcover_batch._cache_size()
+    svc.answer_batch(pool, five)
+    assert maxcover._greedy_maxcover_batch._cache_size() == compiled
 
 
 def test_solver_quad_agrees_on_batch(pool):
@@ -252,8 +268,9 @@ def test_admit_validates(graph):
 
 
 def test_per_query_state_bytes_model():
-    # covered words + seed slots + gain slots + exclusion slots, 4B each
-    assert svc.per_query_state_bytes(8, 3, 1) == 4 * (8 + 3 + 3 + 1)
+    # covered words + selected rows + seed slots + gain slots +
+    # exclusion slots, 4B each
+    assert svc.per_query_state_bytes(8, 3, 1) == 4 * (8 + 3 * 8 + 3 + 3 + 1)
 
 
 # ---------------------------------------------------------------------

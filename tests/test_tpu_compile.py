@@ -74,8 +74,9 @@ def test_sender_compiles(one_chip, solver, n, w):
 @pytest.mark.parametrize("w", [512, 32])
 @pytest.mark.parametrize("solver", ["resident", "lazy"])
 def test_batched_service_solve_compiles(one_chip, monkeypatch, solver, w):
-    """The service's vmapped solve: 8 seed-constrained queries over one
-    shared pool (``maxcover.greedy_maxcover_batch``)."""
+    """The service's batched solve: 8 seed-constrained queries over one
+    shared pool (``maxcover.greedy_maxcover_batch``) — one shared-stream
+    kernel for "resident", the vmapped kernel for "lazy"."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     rows = _sds(one_chip, (2 ** 18, w), jnp.uint32)
     excl = _sds(one_chip, (8, 6), jnp.int32)
